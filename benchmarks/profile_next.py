@@ -35,17 +35,24 @@ from repro.core.modeling import dataset as ds  # noqa: E402
 from repro.core.workloads import list_workloads  # noqa: E402
 
 
-def covered_programs(cache_path=None) -> list[str]:
-    """Programs with at least one profiled cell in the cache, in suite
-    order (cache keys are ``program@scale``)."""
+def _profiled_here(cache_path=None) -> set[str]:
+    """Programs with at least one cell in the cache measured on this
+    process's kind of device."""
+    import jax
+
+    kind = jax.devices()[0].device_kind
     cache = ds._load_cache(cache_path or ds.default_cache_path())
-    have = {k.rsplit("@", 1)[0] for k in cache}
+    return {e["program"] for e in cache.values() if e["device_kind"] == kind}
+
+
+def covered_programs(cache_path=None) -> list[str]:
+    """Covered programs (see :func:`_profiled_here`), in suite order."""
+    have = _profiled_here(cache_path)
     return [p for p in list_workloads() if p in have]
 
 
 def next_uncovered(count: int, cache_path=None) -> list[str]:
-    cache = ds._load_cache(cache_path or ds.default_cache_path())
-    have = {k.rsplit("@", 1)[0] for k in cache}
+    have = _profiled_here(cache_path)
     return [p for p in list_workloads() if p not in have][:count]
 
 

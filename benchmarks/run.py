@@ -21,9 +21,11 @@ by benchmarks/dryrun_sweep.py) is appended when available.
 from __future__ import annotations
 
 import argparse
+import concurrent.futures
 import dataclasses
 import glob
 import json
+import multiprocessing
 import os
 import sys
 import time
@@ -46,6 +48,7 @@ if ("--serve-concurrent" in sys.argv or "--serve-oracle" in sys.argv
 import numpy as np  # noqa: E402
 
 from repro.core import dataset as ds  # noqa: E402
+from repro.core.compile_cache import enable_compile_cache  # noqa: E402
 from repro.core.backends import list_backends  # noqa: E402
 from repro.core.stream_config import StreamConfig  # noqa: E402
 from repro.core.streams import (StreamedRunner,  # noqa: E402
@@ -378,6 +381,10 @@ def serve_fleet(programs=None, *, n_workers: int = 4, n_requests: int = 24,
     i.e. what dispatch, pickling, and collection cost; lower is better
     and CI gates it.
 
+    This process starts no JAX backend (each worker owns a chip), so
+    the capacity probe runs first, in a child process of its own that
+    exits before any worker starts.
+
     The kill drill reuses the max-N fleet: SIGKILL one worker mid-trace,
     assert the router respawns the slot, requeues the un-acked work, and
     every admitted request still reaches a terminal status —
@@ -396,6 +403,10 @@ def serve_fleet(programs=None, *, n_workers: int = 4, n_requests: int = 24,
                           )[:n_requests]
 
     counts = sorted({n for n in (1, 2, 4) if n <= n_workers} | {n_workers})
+    with concurrent.futures.ProcessPoolExecutor(
+            1, mp_context=multiprocessing.get_context("spawn")) as probe:
+        capacity = probe.submit(_parallel_capacity, programs, scale_index,
+                                n_workers).result()
     rows, walls, crashes = [], {}, 0
     latency, ipc = {}, {}
     router = None
@@ -447,7 +458,6 @@ def serve_fleet(programs=None, *, n_workers: int = 4, n_requests: int = 24,
                 router = None
 
         speedup = walls[1] / max(walls[n_workers], 1e-12)
-        capacity = _parallel_capacity(programs, scale_index, n_workers)
         ceiling = min(float(n_workers), max(1.0, capacity))
         scaling_fraction = speedup / ceiling
         rows.append(f"serve_fleet.capacity.{n_workers}procs,0,"
@@ -1326,6 +1336,7 @@ def main() -> None:
     ap.add_argument("--eval-datasets", type=int, default=2,
                     help="dataset scales per program for --model-eval")
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.model_eval:
         print("name,us_per_call,derived")

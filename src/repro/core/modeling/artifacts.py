@@ -51,13 +51,15 @@ def feature_schema_hash() -> str:
 
 def corpus_fingerprint(samples: Sequence) -> str:
     """Order-independent digest of a profiled training corpus: which
-    (program, dataset) cells it covers and how densely each was swept.
-    Stamped into the manifest (and used as the CI profile-cache key
-    material) so 'same corpus' is checkable without re-profiling."""
+    (program, dataset) cells it covers, how densely each was swept, and
+    which kind of device measured them.  Stamped into the manifest (and
+    used as the CI profile-cache key material) so 'same corpus' is
+    checkable without re-profiling."""
     h = hashlib.sha256()
-    for s in sorted(samples, key=lambda s: (s.program, s.scale)):
+    for s in sorted(samples, key=lambda s: (s.device_kind, s.program,
+                                            s.scale)):
         cfgs = ",".join(f"{p}x{t}" for p, t in sorted(s.times))
-        h.update(f"{s.program}@{s.scale}:[{cfgs}];".encode())
+        h.update(f"{s.device_kind}/{s.program}@{s.scale}:[{cfgs}];".encode())
     return h.hexdigest()[:16]
 
 

@@ -4,6 +4,10 @@ Exhaustively profiles every (program, dataset, stream-config) cell, caches
 the results as JSON (profiling is the expensive one-off "at the factory"
 step), and assembles (features ++ config) -> speedup training matrices with
 leave-one-out splits over *programs*.
+
+Every cached sample is stamped with the ``device_kind`` that measured it
+and keyed ``device_kind/program@scale``: a measurement taken on one kind
+of device is never read as one of another.
 """
 from __future__ import annotations
 
@@ -47,6 +51,7 @@ class Sample:
     features: np.ndarray                 # (22,) raw features
     t_single: float                      # single-stream seconds
     times: dict                          # {(p, t): seconds}
+    device_kind: str                     # jax ``Device.device_kind``
 
     def speedup(self, cfg: StreamConfig) -> float:
         return self.t_single / self.times[cfg.as_tuple()]
@@ -67,6 +72,7 @@ class Sample:
             "features": self.features.tolist(),
             "t_single": self.t_single,
             "times": [[p, t, v] for (p, t), v in self.times.items()],
+            "device_kind": self.device_kind,
         }
 
     @staticmethod
@@ -75,7 +81,27 @@ class Sample:
             d["program"], d["scale"], np.asarray(d["features"], np.float64),
             d["t_single"],
             {(p, t): v for p, t, v in d["times"]},
+            d["device_kind"],
         )
+
+
+def cache_key(program: str, scale: int, device_kind: str) -> str:
+    return f"{device_kind}/{program}@{scale}"
+
+
+def cached_sample(cache: dict, program: str, scale: int,
+                  device_kind: str) -> Optional[Sample]:
+    """The cached measurement of (program, scale) taken on
+    ``device_kind``, or None — never one taken on another device."""
+    entry = cache.get(cache_key(program, scale, device_kind))
+    if entry is None:
+        return None
+    sample = Sample.from_json(entry)
+    if sample.device_kind != device_kind:
+        raise ValueError(
+            f"profile cache entry {cache_key(program, scale, device_kind)!r} "
+            f"was measured on {sample.device_kind!r}")
+    return sample
 
 
 def grid_for(n_rows: int, max_partitions: int = 32,
@@ -95,7 +121,8 @@ def profile_sample(program: str, scale: int, *, reps: int = 2,
     times = profile_config_grid(runner, grid, reps=reps)
     t_single = times[StreamConfig(1, 1)]
     return Sample(program, scale, feats.values, t_single,
-                  {c.as_tuple(): v for c, v in times.items()})
+                  {c.as_tuple(): v for c, v in times.items()},
+                  runner.device.device_kind)
 
 
 def generate(
@@ -106,20 +133,25 @@ def generate(
     cache_path: "str | Path | None" = None,
     verbose: bool = True,
 ) -> list[Sample]:
-    """Profile (or load cached) samples for the suite."""
+    """Profile (or load cached) samples for the suite, on the default
+    device; only cached samples that device's kind measured are read."""
+    import jax
+
     programs = list(programs or list_workloads())
     cache_path = Path(cache_path) if cache_path else default_cache_path()
     cache = _load_cache(cache_path)
+    device_kind = jax.devices()[0].device_kind
     samples: list[Sample] = []
     dirty = False
     for prog in programs:
         wl = get_workload(prog)
         scales = _pick_scales(wl.datasets, datasets_per_program)
         for scale in scales:
-            key = f"{prog}@{scale}"
-            if key in cache:
-                samples.append(Sample.from_json(cache[key]))
+            cached = cached_sample(cache, prog, scale, device_kind)
+            if cached is not None:
+                samples.append(cached)
                 continue
+            key = cache_key(prog, scale, device_kind)
             t0 = time.perf_counter()
             s = profile_sample(prog, scale, reps=reps)
             cache[key] = s.to_json()

@@ -1,9 +1,7 @@
-"""Version-tolerant access to XLA's ``compiled.cost_analysis()``.
+"""Access to XLA's ``compiled.cost_analysis()``.
 
-Across jax releases the return type has flipped between a dict and a
-list-of-dicts (one per computation, entry 0 = the entry computation).
-Every consumer in this repo goes through :func:`cost_analysis_dict` so the
-difference is absorbed in exactly one place.
+Every consumer in this repo goes through :func:`cost_analysis_dict`, so
+the one rule about a missing analysis lives in one place.
 """
 from __future__ import annotations
 
@@ -11,19 +9,10 @@ from __future__ import annotations
 def cost_analysis_dict(compiled) -> dict:
     """Return the entry-computation cost analysis as a plain dict.
 
-    Returns ``{}`` when the backend has no cost analysis at all — callers
-    fall back to their own estimates in that case.
+    Returns ``{}`` only when the backend has no cost analysis at all
+    (JAX then reports ``None``); callers fall back to their own
+    estimates in that case.  Any failure of a backend that does have
+    one propagates.
     """
-    try:
-        cost = compiled.cost_analysis()
-    # cost_analysis availability/shape is backend-specific; an
-    # unsupported backend means "no estimate", not a crash
-    except Exception:  # noqa: BLE001
-        return {}
-    if cost is None:
-        return {}
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    if not isinstance(cost, dict):
-        return {}
-    return dict(cost)
+    cost = compiled.cost_analysis()
+    return {} if cost is None else dict(cost)

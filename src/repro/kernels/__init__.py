@@ -1,3 +1,9 @@
-# OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
-# for compute hot-spots the paper itself optimizes with a custom
-# kernel. Leave this package empty if the paper has none.
+"""Pallas TPU kernels (``flash_attention``, ``rmsnorm``) with pure-jnp
+references in ``ref.py`` and jitted wrappers in ``ops.py``."""
+import jax
+
+
+def default_interpret() -> bool:
+    """Pallas lowers to Mosaic only on a TPU backend; on any other
+    backend a kernel runs in the Pallas interpreter."""
+    return jax.default_backend() != "tpu"

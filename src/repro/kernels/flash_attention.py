@@ -11,7 +11,8 @@ Grid: (batch*kv_head, q_blocks, kv_blocks); kv is the innermost
 scratch across kv steps of one (bh, q_block) tile.  Causal skipping is
 predicated with pl.when so fully-masked kv blocks do no MXU work.
 
-Validated on CPU via interpret=True against repro.kernels.ref (pure jnp).
+Validated against repro.kernels.ref (pure jnp) in the Pallas interpreter
+on CPU; compiled with Mosaic on a TPU.
 """
 from __future__ import annotations
 
@@ -21,6 +22,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import default_interpret
 
 _NEG_INF = -1e30
 
@@ -86,7 +89,7 @@ def flash_attention_pallas(
     causal: bool = True,
     q_block: int = 128,
     kv_block: int = 128,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     """GQA flash attention. The G query heads of one KV group are folded
     into the q-block rows so each MXU tile is (q_block*G, head_dim) and K/V
@@ -140,7 +143,7 @@ def flash_attention_pallas(
             pltpu.VMEM((q_block * group, 1), jnp.float32),    # m
             pltpu.VMEM((q_block * group, 1), jnp.float32),    # l
         ],
-        interpret=interpret,
+        interpret=default_interpret() if interpret is None else interpret,
     )(qg, kg, vg)
 
     # (B*KV, nq, q_block*G, hd) -> (B, Sq, H, hd)

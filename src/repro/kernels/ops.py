@@ -1,13 +1,11 @@
 """Jit'd public wrappers around the Pallas kernels.
 
-``interpret`` defaults to True because this container is CPU-only; on a
-real TPU runtime set ``REPRO_PALLAS_INTERPRET=0`` (or pass interpret=False)
-to lower to Mosaic.
+``interpret=None`` (the default) follows the backend: compiled on a TPU,
+the Pallas interpreter elsewhere (:func:`repro.kernels.default_interpret`).
 """
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 
@@ -15,15 +13,10 @@ from repro.kernels.flash_attention import flash_attention_pallas
 from repro.kernels.rmsnorm import rmsnorm_pallas
 
 
-def _default_interpret() -> bool:
-    return os.environ.get("REPRO_PALLAS_INTERPRET", "1") != "0"
-
-
 @functools.partial(jax.jit, static_argnames=("causal", "q_block", "kv_block",
                                              "interpret"))
 def flash_attention(q, k, v, *, causal: bool = True, q_block: int = 128,
                     kv_block: int = 128, interpret: bool | None = None):
-    interpret = _default_interpret() if interpret is None else interpret
     return flash_attention_pallas(
         q, k, v, causal=causal, q_block=q_block, kv_block=kv_block,
         interpret=interpret)
@@ -32,6 +25,5 @@ def flash_attention(q, k, v, *, causal: bool = True, q_block: int = 128,
 @functools.partial(jax.jit, static_argnames=("eps", "row_block", "interpret"))
 def rmsnorm(x, scale, *, eps: float = 1e-5, row_block: int = 256,
             interpret: bool | None = None):
-    interpret = _default_interpret() if interpret is None else interpret
     return rmsnorm_pallas(x, scale, eps=eps, row_block=row_block,
                           interpret=interpret)

@@ -12,6 +12,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import default_interpret
+
 
 def _rmsnorm_kernel(x_ref, scale_ref, o_ref, *, eps: float):
     x = x_ref[...].astype(jnp.float32)
@@ -21,8 +23,9 @@ def _rmsnorm_kernel(x_ref, scale_ref, o_ref, *, eps: float):
 
 
 def rmsnorm_pallas(x: jax.Array, scale: jax.Array, *, eps: float = 1e-5,
-                   row_block: int = 256, interpret: bool = True) -> jax.Array:
-    """x (..., d), scale (d,)."""
+                   row_block: int = 256,
+                   interpret: bool | None = None) -> jax.Array:
+    """x (..., d), scale (d,).  ``interpret=None`` follows the backend."""
     orig_shape = x.shape
     d = x.shape[-1]
     xf = x.reshape(-1, d)
@@ -41,7 +44,7 @@ def rmsnorm_pallas(x: jax.Array, scale: jax.Array, *, eps: float = 1e-5,
         ],
         out_specs=pl.BlockSpec((rb, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct(xf.shape, x.dtype),
-        interpret=interpret,
+        interpret=default_interpret() if interpret is None else interpret,
     )(xf, scale)
     if pad:
         out = out[:n]

@@ -29,6 +29,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import get_arch, list_archs
+from repro.core.compile_cache import enable_compile_cache
 from repro.models.model_zoo import Model
 from repro.models.transformer import RunConfig
 
@@ -120,6 +121,17 @@ def serve(
 DEFAULT_ADAPTIVE_WORKLOADS = ("vecadd", "dotprod", "mvmult")
 
 
+def _model_info(spec: str, manifest: Optional[dict] = None) -> dict:
+    """The summary's ``model`` block; no manifest means the heuristic."""
+    if manifest is None:
+        return {"spec": spec, "kind": "heuristic", "artifact_id": "heuristic"}
+    return {"spec": spec, "kind": manifest["kind"],
+            "artifact_id": manifest["artifact_id"],
+            "corpus_fingerprint": manifest.get("corpus_fingerprint"),
+            "cv_frac_of_oracle": (manifest.get("cv") or {}).get(
+                "frac_of_oracle")}
+
+
 def resolve_serving_model(spec: str = "latest", model_dir=None, *,
                           bootstrap: bool = True, verbose: bool = True,
                           metrics=None):
@@ -141,8 +153,7 @@ def resolve_serving_model(spec: str = "latest", model_dir=None, *,
     from repro.core.modeling.registry import ModelRegistry
 
     if spec == "heuristic":
-        return OverlapHeuristicModel(), {
-            "spec": spec, "kind": "heuristic", "artifact_id": "heuristic"}
+        return OverlapHeuristicModel(), _model_info(spec)
     registry = ModelRegistry(model_dir, metrics=metrics)
     try:
         model, manifest = registry.load(spec)
@@ -152,11 +163,7 @@ def resolve_serving_model(spec: str = "latest", model_dir=None, *,
         from repro.launch.train_model import bootstrap_artifact
         artifact_id = bootstrap_artifact(registry, verbose=verbose)
         model, manifest = registry.load(artifact_id)
-    info = {"spec": spec, "kind": manifest["kind"],
-            "artifact_id": manifest["artifact_id"],
-            "corpus_fingerprint": manifest.get("corpus_fingerprint"),
-            "cv_frac_of_oracle": (manifest.get("cv") or {}).get(
-                "frac_of_oracle")}
+    info = _model_info(spec, manifest)
     if verbose:
         print(f"serving model: {info['artifact_id']} "
               f"(kind={info['kind']}, registry={registry.root})",
@@ -369,26 +376,29 @@ def fleet_serve(
     """Serve a mixed multi-tenant trace through the fleet router:
     ``worker_procs`` spawn-isolated worker processes, each running its
     own :class:`~repro.serving.ConcurrentScheduler` with ``window``
-    requests in flight, tenants sharded stably across them (README
-    "Fleet serving").
+    requests in flight on a chip of its own, tenants sharded stably
+    across them (README "Fleet serving").
 
-    The model spec is resolved *once* here (bootstrap-training if the
-    registry is empty, exactly like single-process serving) and the
-    pinned artifact id is what ships to the workers — N processes load
-    one immutable registry version instead of racing ``latest``.
+    This process never starts a JAX backend: every chip belongs to a
+    worker.  So the model spec is resolved *once* here from the
+    registry manifest, without loading weights, and the pinned artifact
+    id is what ships to the workers — N processes load one immutable
+    registry version instead of racing ``latest``.  Fleet mode needs a
+    published artifact (``launch/train_model.py``, or one bootstrap run
+    of single-process ``--adaptive`` serving); an empty registry is an
+    error here, not a bootstrap.
     Returns the merged fleet summary: worker-labeled telemetry
     aggregates, a ``per_worker`` breakdown, respawn/death counters, and
     (when ``metrics_out`` is set) the merged worker-labeled metrics
     snapshot, which ``repro.launch.stats --metrics`` renders unchanged.
     """
+    from repro.core.modeling.registry import ModelRegistry
     from repro.serving import make_trace
     from repro.serving.fleet import FleetRouter, WorkerConfig
 
-    model_obj, model_info = resolve_serving_model(
-        model, model_dir, verbose=verbose)
-    del model_obj                     # workers load their own copy
-    spec = (model_info["artifact_id"]
-            if model_info["kind"] != "heuristic" else "heuristic")
+    model_info = _model_info(model, None if model == "heuristic" else
+                             ModelRegistry(model_dir).manifest(model))
+    spec = model_info["artifact_id"]
     occurrences = -(-n_requests // len(workloads))  # ceil
     trace = make_trace(list(workloads), occurrences=occurrences,
                        tenants=max(tenants, 1), seed=seed)[:n_requests]
@@ -470,7 +480,9 @@ def main() -> None:
                          "worker PROCESSES (tenant-sharded, respawn on "
                          "death; implies --adaptive).  Each worker runs "
                          "its own concurrent engine with --window "
-                         "requests in flight; 0 = single-process")
+                         "requests in flight on a chip of its own; needs "
+                         "a published --model (no bootstrap); "
+                         "0 = single-process")
     ap.add_argument("--wire", default="auto",
                     choices=["auto", "v2", "legacy"],
                     help="fleet result wire: 'v2' batched frames of "
@@ -510,6 +522,7 @@ def main() -> None:
                          "injection (implies --resilience; see "
                          "benchmarks/data/chaos_faults.json)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.worker_procs and args.worker_procs > 0:
         summary = fleet_serve(

@@ -24,6 +24,7 @@ import sys
 import time
 from typing import Optional, Sequence
 
+from repro.core.compile_cache import enable_compile_cache
 from repro.core.modeling import dataset as ds
 from repro.core.modeling.artifacts import corpus_fingerprint
 from repro.core.modeling.evaluate import loo_evaluate
@@ -169,6 +170,7 @@ def main() -> None:
     ap.add_argument("--no-cv", action="store_true",
                     help="skip leave-one-program-out cross-validation")
     args = ap.parse_args()
+    enable_compile_cache()
 
     summary = train_and_publish(
         args.programs.split(",") if args.programs else None,

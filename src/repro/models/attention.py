@@ -12,7 +12,6 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 
@@ -235,12 +234,12 @@ def decode_attention_sharded(
         out = out.reshape(b_loc, 1, H, hd)
         return out, k_loc, v_loc
 
-    fn = shard_map(
+    fn = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(rep_spec, rep_spec, rep_spec, cache_spec, cache_spec, P()),
         out_specs=(rep_spec, cache_spec, cache_spec),
-        check_rep=False,
+        check_vma=False,
     )
     return fn(q, k_new, v_new, k_cache, v_cache, t)
 

@@ -51,7 +51,7 @@ def _aval_bytes(aval) -> float:
     try:
         return float(np.prod(aval.shape, dtype=np.float64)
                      * np.dtype(aval.dtype).itemsize)
-    # jaxpr avals are duck-typed across jax versions; any aval
+    # an extended dtype (a PRNG key) has no NumPy itemsize; any aval
     # that won't yield a byte size costs 0, never a crash
     except Exception:  # noqa: BLE001
         return 0.0
@@ -146,28 +146,13 @@ def _eqn_cost(eqn) -> Cost:
     if prim == "cond":
         branches = [jaxpr_cost(b.jaxpr) for b in eqn.params["branches"]]
         return max(branches, key=lambda c: c.flops) if branches else Cost()
-    if prim in ("shard_map", "smap"):
+    if prim == "shard_map":
         # body avals are per-shard: scale by the mapped device count so the
         # walker's global-cost convention holds
-        n_dev = 1
-        mesh = eqn.params.get("mesh")
-        if mesh is not None:
-            try:
-                n_dev = int(np.prod(list(dict(mesh.shape).values())))
-            # mesh.shape layout varies across jax versions
-            except Exception:  # noqa: BLE001
-                n_dev = getattr(mesh, "size", 1)
         sub = Cost()
         for j in _sub_jaxprs(eqn.params):
             sub = sub + jaxpr_cost(j)
-        return sub * n_dev
-    if prim in ("pjit", "closed_call", "core_call", "remat_call",
-                "custom_jvp_call", "custom_vjp_call",
-                "custom_vjp_call_jaxpr", "checkpoint", "remat", "remat2"):
-        sub = Cost()
-        for j in _sub_jaxprs(eqn.params):
-            sub = sub + jaxpr_cost(j)
-        return sub
+        return sub * eqn.params["mesh"].size
     if prim == "dot_general":
         return Cost(_dot_flops(eqn), io_bytes)
     if prim == "conv_general_dilated":

@@ -33,6 +33,13 @@ terminally (synthetic ``failed`` telemetry) instead of looping — a
 submitted request always reaches a terminal status, the same contract
 the chaos harness gates.
 
+Each slot's worker gets a chip of its own: before a spawn, the router
+pins the child's environment to chip ``index`` with libtpu's per-process
+bounds (:func:`chip_env`).  Those variables mean nothing to other
+backends, so a fleet on a CPU host runs unchanged.  The router itself
+never starts a JAX backend — a chip belongs to one process at a time,
+and every one of them belongs to a worker.
+
 Telemetry and metrics aggregate centrally: every result carries its
 worker-labeled sample, appended live to the router's fleet
 :class:`TelemetryLog` (and observed by a fleet-level
@@ -49,10 +56,12 @@ its ``MetricsRegistry`` snapshot in the goodbye handshake and
 from __future__ import annotations
 
 import collections
+import contextlib
 import dataclasses
 import multiprocessing
 import os
 import signal
+import socket
 import time
 import zlib
 from typing import Dict, List, Optional
@@ -90,6 +99,39 @@ def shard_for(tenant: str, n_workers: int) -> int:
     return zlib.crc32(tenant.encode("utf-8")) % max(1, n_workers)
 
 
+def chip_env(index: int) -> Dict[str, str]:
+    """libtpu settings that give one process chip ``index`` of its host
+    and nothing else: one-chip process bounds (which also let several
+    processes load libtpu side by side) and a slice-builder port of its
+    own."""
+    with socket.socket() as sock:
+        sock.bind(("localhost", 0))
+        port = sock.getsockname()[1]
+    return {
+        "TPU_VISIBLE_CHIPS": str(index),
+        "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+        "TPU_PROCESS_BOUNDS": "1,1,1",
+        "TPU_PROCESS_PORT": str(port),
+        "TPU_PROCESS_ADDRESSES": f"localhost:{port}",
+    }
+
+
+@contextlib.contextmanager
+def _spawn_env(overrides: Dict[str, str]):
+    """``overrides`` in this process's environment for the duration —
+    a spawned child copies the environment at ``start()``."""
+    saved = {k: os.environ.get(k) for k in overrides}
+    os.environ.update(overrides)
+    try:
+        yield
+    finally:
+        for k, v in saved.items():
+            if v is None:
+                os.environ.pop(k, None)
+            else:
+                os.environ[k] = v
+
+
 def _ensure_child_pythonpath() -> None:
     """Spawn children re-import ``repro`` from scratch and do NOT
     inherit the parent's ``sys.path`` edits (the ``sys.path.insert``
@@ -122,6 +164,8 @@ class _Slot:
     conn: object
     pid: Optional[int] = None
     model_tag: Optional[str] = None
+    #: the device the worker serves on: platform, kind, chip nodes held
+    device: Optional[dict] = None
     respawns: int = 0
     #: un-acked work: token → the router's retained request copy,
     #: insertion == admission order (dicts preserve it) for fair requeue
@@ -244,7 +288,8 @@ class FleetRouter:
         proc = self._ctx.Process(
             target=worker_main, args=(cfg, task_q, send_conn),
             name=f"fleet-{cfg.label}", daemon=True)
-        proc.start()
+        with _spawn_env(chip_env(index)):
+            proc.start()
         # the child owns the ONLY write end from here on: when it dies,
         # the pipe EOFs and a half-sent frame raises EOFError in
         # _drain_slot instead of blocking a read forever
@@ -269,8 +314,7 @@ class FleetRouter:
                 pass
             if msg is not None:
                 if msg[0] == "ready":
-                    slot.pid = msg[2]
-                    slot.model_tag = msg[3]
+                    _, _, slot.pid, slot.model_tag, slot.device = msg
                     return
                 if msg[0] == "fatal":
                     raise RuntimeError(
@@ -652,6 +696,7 @@ class FleetRouter:
     def summary(self) -> dict:
         s = fleet_summary(self.telemetry.samples)
         s["workers"] = self.n_workers
+        s["devices"] = {slot.label: slot.device for slot in self._slots}
         s["worker_deaths"] = self.stats.get("worker_deaths", 0)
         s["worker_respawns"] = self.stats.get("worker_respawns", 0)
         s["injected_kills"] = self.stats.get("injected_kills", 0)

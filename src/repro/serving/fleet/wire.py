@@ -11,7 +11,7 @@ element is the message kind:
     ("stop",)                                    graceful shutdown
 
   worker → router (result connection)
-    ("ready", label, pid, model_tag)             startup handshake
+    ("ready", label, pid, model_tag, device)     startup handshake
     ("results", label, version, busy_s, [(token, row), ...])
                                                  one *frame* of terminal
                                                  results (wire v2)

@@ -28,6 +28,11 @@ messages.
 preserves it (``RequestQueue.push`` only assigns when unset), so results
 map back to router bookkeeping without a shared sequence space.
 
+A worker serves on the first device of the platform its process was
+given: JAX's own settings in the environment it was spawned with, plus
+the per-slot chip the router pins on a TPU host.  It reports that device
+in its ``ready`` handshake.
+
 Workers default to a :class:`ResiliencePolicy`: a bad request fails
 *individually* (terminal ``failed`` result) instead of taking the
 process down.  Anything that still escapes — a scheduler bug, an OOM —
@@ -41,6 +46,7 @@ from __future__ import annotations
 import dataclasses
 import os
 import queue as queue_mod
+import re
 import time
 from typing import Optional
 
@@ -195,6 +201,28 @@ def _serve_batch(sched, cfg: WorkerConfig, batch, conn, wire: str) -> None:
     _send_results(conn, cfg.label, results, busy, wire, cfg.frame_max)
 
 
+#: device nodes of accelerator chips (TPU v5e and later: vfio groups)
+_CHIP_NODE = re.compile(r"/dev/(vfio/\d+|accel\d+)")
+
+
+def _device_info() -> dict:
+    """The device this worker serves on (serving builds every
+    ExecutionContext on ``jax.devices()[0]``).  JAX numbers a pinned
+    process's only chip 0 whichever chip it is, so the chip itself is
+    named by the device nodes the process holds open."""
+    import jax
+
+    d = jax.devices()[0]
+    held = set()
+    for fd in os.listdir("/proc/self/fd"):
+        try:
+            held.add(os.readlink(f"/proc/self/fd/{fd}"))
+        except OSError:          # closed since the listing
+            pass
+    return {"platform": d.platform, "kind": d.device_kind,
+            "chip_nodes": sorted(p for p in held if _CHIP_NODE.fullmatch(p))}
+
+
 def _refresh(sched, cfg: WorkerConfig, spec: str):
     from repro.launch.serve import resolve_serving_model
     model, info = resolve_serving_model(
@@ -208,15 +236,18 @@ def worker_main(cfg: WorkerConfig, task_q, conn) -> None:
     spawn re-imports the target by qualified name, so a closure or
     ``__main__`` function would break under pytest and ``-m`` entry
     points).  ``conn`` is the send end of this worker's result pipe."""
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
+    from repro.core.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     label = cfg.label
     wire = resolve_wire_mode(cfg.wire)
     try:
         sched, model_tag = _build_scheduler(cfg)
+        device = _device_info()
     except BaseException as e:  # noqa: BLE001 — report, then die loudly
         conn.send(("fatal", label, f"{type(e).__name__}: {e}"))
         raise SystemExit(1)
-    conn.send(("ready", label, os.getpid(), model_tag))
+    conn.send(("ready", label, os.getpid(), model_tag, device))
 
     try:
         pending_ctrl = None

@@ -4,8 +4,12 @@ import sys
 import pytest
 
 # tests must see the real single CPU device (the 512-device flag is only
-# ever set inside launch/dryrun.py's own process)
+# ever set inside launch/dryrun.py's own process); the chip is reached
+# only through chip_smoke.py
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
+# tests never write JAX's persistent compilation cache, and neither do
+# the fleet workers and subprocesses they start (which inherit this)
+os.environ["JAX_ENABLE_COMPILATION_CACHE"] = "false"
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 SRC = os.path.join(ROOT, "src")

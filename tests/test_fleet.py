@@ -247,3 +247,87 @@ def test_fleet_respawn_budget_exhaustion_fails_terminally_and_closes():
     fr.close()                                   # idempotent
     assert _fleet_children() == []
     assert fr.summary()["requests"] == len(reqs) + len(more)
+
+
+# -- placement: the platform a worker was given, one chip per slot -----------
+
+
+def _environ_of(pid: int) -> dict:
+    with open(f"/proc/{pid}/environ", "rb") as f:
+        items = f.read().decode().split("\0")
+    return dict(item.split("=", 1) for item in items if "=" in item)
+
+
+def test_fleet_workers_keep_their_platform_and_get_one_chip_each():
+    """No code under src/ picks JAX's platform for a process: each worker
+    serves on the platform it was given (here the test run's CPU), and
+    the router pins every slot's child environment to a chip of its own
+    without leaking the pin into its own environment."""
+    src = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "src")
+    for root, _, files in os.walk(src):
+        for name in files:
+            if name.endswith(".py"):
+                with open(os.path.join(root, name)) as f:
+                    assert "JAX_PLATFORMS" not in f.read(), name
+
+    given = os.environ["JAX_PLATFORMS"]
+    with FleetRouter(2, worker=WorkerConfig(model="heuristic")) as fr:
+        devices = fr.summary()["devices"]
+        envs = [_environ_of(slot.pid) for slot in fr._slots]
+    assert {d["platform"] for d in devices.values()} == {given}
+    assert [e["JAX_PLATFORMS"] for e in envs] == [given, given]
+    assert [e["TPU_VISIBLE_CHIPS"] for e in envs] == ["0", "1"]
+    assert all(e["TPU_CHIPS_PER_PROCESS_BOUNDS"] == "1,1,1" for e in envs)
+    assert len({e["TPU_PROCESS_PORT"] for e in envs}) == 2
+    assert "TPU_VISIBLE_CHIPS" not in os.environ
+    assert _fleet_children() == []
+
+
+def test_fleet_serve_parent_never_starts_a_jax_backend(tmp_path):
+    """Every chip belongs to a worker: ``fleet_serve`` resolves the
+    published artifact from its manifest and ships the id, so the
+    serving parent never initializes a backend.  An empty registry is an
+    error here, not a bootstrap."""
+    import json
+    import subprocess
+    import sys
+
+    import numpy as np
+
+    from repro.core.features import RAW_FEATURE_NAMES, config_features
+    from repro.core.modeling import TreeRegressor
+    from repro.core.modeling.registry import ModelRegistry
+    from repro.launch.serve import fleet_serve
+
+    with pytest.raises(FileNotFoundError, match="no 'latest' artifact"):
+        fleet_serve(["vecadd"], model_dir=str(tmp_path / "empty"),
+                    verbose=False)
+
+    rng = np.random.default_rng(0)
+    X = np.stack([np.concatenate([rng.uniform(0.5, 2, len(RAW_FEATURE_NAMES)),
+                                  config_features(2 ** (i % 3), 2 ** (i % 5))])
+                  for i in range(40)])
+    registry = ModelRegistry(tmp_path / "models")
+    artifact = registry.publish(TreeRegressor.train(X, rng.uniform(1, 2, 40),
+                                                    depth=3))
+    code = """
+import json, sys
+from jax._src import xla_bridge
+from repro.launch.serve import fleet_serve
+if __name__ == "__main__":
+    s = fleet_serve(["vecadd"], n_requests=4, worker_procs=1,
+                    model_dir=sys.argv[1], verbose=False)
+    print(json.dumps({"artifact": s["model"]["artifact_id"],
+                      "by_status": s["by_status"],
+                      "backends": xla_bridge.backends_are_initialized()}))
+"""
+    src = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "src")
+    r = subprocess.run([sys.executable, "-c", code, str(tmp_path / "models")],
+                       env=dict(os.environ, PYTHONPATH=src),
+                       capture_output=True, text=True, timeout=240)
+    assert r.returncode == 0, r.stderr[-3000:]
+    out = json.loads(r.stdout.strip().splitlines()[-1])
+    assert out == {"artifact": artifact, "by_status": {"ok": 4},
+                   "backends": False}

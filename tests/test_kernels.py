@@ -63,3 +63,15 @@ def test_rmsnorm_3d():
     x = jax.random.normal(jax.random.key(3), (2, 17, 64))
     s = jnp.ones((64,))
     assert jnp.allclose(rmsnorm(x, s), rmsnorm_ref(x, s), atol=1e-5)
+
+
+def test_interpret_mode_follows_the_backend(monkeypatch):
+    """Mosaic on a TPU backend, the Pallas interpreter anywhere else —
+    decided from the backend, with no switch to forget."""
+    from repro.kernels import default_interpret
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert default_interpret() is False
+    for backend in ("cpu", "gpu"):
+        monkeypatch.setattr(jax, "default_backend", lambda b=backend: b)
+        assert default_interpret() is True

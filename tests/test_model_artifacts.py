@@ -7,6 +7,7 @@ import json
 import numpy as np
 import pytest
 
+from repro.core import REPO_ROOT
 from repro.core.features import RAW_FEATURE_NAMES, config_features
 from repro.core.modeling import (ESTIMATOR_KINDS, Estimator, FeaturePipeline,
                                  ForestRegressor, KernelRidgeRBF,
@@ -124,8 +125,9 @@ def test_loaded_model_refits_independently(models, tmp_path):
 
 def test_corpus_fingerprint_is_order_independent():
     class S:
-        def __init__(self, program, scale, times):
+        def __init__(self, program, scale, times, device_kind="cpu"):
             self.program, self.scale, self.times = program, scale, times
+            self.device_kind = device_kind
 
     a = [S("x", 1, {(1, 1): 0.1}),
          S("y", 2, {(1, 1): 0.2, (2, 4): 0.3})]
@@ -135,6 +137,9 @@ def test_corpus_fingerprint_is_order_independent():
     # a different config GRID of the same size is a different corpus
     c = [a[0], S("y", 2, {(1, 1): 0.2, (4, 2): 0.3})]
     assert corpus_fingerprint(a) != corpus_fingerprint(c)
+    # so are the same cells measured on another kind of device
+    d = [S(s.program, s.scale, s.times, "TPU v5 lite") for s in a]
+    assert corpus_fingerprint(a) != corpus_fingerprint(d)
 
 
 # -- FeaturePipeline degenerate inputs (regression: used to rely on
@@ -186,3 +191,37 @@ def test_degenerate_training_still_serves():
     m = PerformanceModel.train(X, y, epochs=30)
     preds = m.predict_configs(feats[0], CANDS)
     assert np.isfinite(preds).all()
+
+
+def test_profile_cache_reads_only_what_this_device_kind_measured(tmp_path):
+    """A cached sample measured on one kind of device is never read as a
+    measurement of another: ``generate`` on the CPU re-profiles a cell
+    that only a TPU measured, and keeps both entries."""
+    import jax
+
+    from repro.core.modeling import dataset as ds
+
+    tpu = ds.Sample("vecadd", 256, np.zeros(N_FEAT), 123.0, {(1, 1): 123.0},
+                    "TPU v5 lite")
+    cache = {ds.cache_key("vecadd", 256, tpu.device_kind): tpu.to_json()}
+    assert ds.cached_sample(cache, "vecadd", 256, "cpu") is None
+    assert ds.cached_sample(cache, "vecadd", 256, "TPU v5 lite").t_single == 123.0
+    # an entry whose stamp disagrees with its key is refused, not read
+    bad = {ds.cache_key("vecadd", 256, "cpu"): tpu.to_json()}
+    with pytest.raises(ValueError, match="measured on"):
+        ds.cached_sample(bad, "vecadd", 256, "cpu")
+
+    path = tmp_path / "profile_cache.json"
+    path.write_text(json.dumps(cache))
+    [sample] = ds.generate(["vecadd"], datasets_per_program=1, reps=1,
+                           cache_path=path, verbose=False)
+    kind = jax.devices()[0].device_kind
+    assert sample.device_kind == kind and sample.t_single != 123.0
+    assert set(json.loads(path.read_text())) == {
+        ds.cache_key("vecadd", 256, "TPU v5 lite"),
+        ds.cache_key("vecadd", 256, kind)}
+
+    # the committed corpus is stamped: it was measured on XLA:CPU
+    committed = json.loads((REPO_ROOT / "benchmarks" / "data"
+                            / "profile_cache.json").read_text())
+    assert {e["device_kind"] for e in committed.values()} == {"cpu"}

@@ -27,8 +27,9 @@ def _run(snippet: str, n_dev: int = 8) -> str:
 def test_sharded_decode_matches_local():
     out = _run("""
 import jax, jax.numpy as jnp
+from repro.launch.mesh import make_test_mesh
 from repro.models.attention import decode_attention_local, decode_attention_sharded
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_test_mesh(2, 4)
 B, S, H, KV, hd = 4, 32, 4, 2, 16
 ks = jax.random.split(jax.random.key(0), 5)
 q = jax.random.normal(ks[0], (B, 1, H, hd))
@@ -53,10 +54,10 @@ print("OK sharded-decode")
 def test_compressed_psum_close_to_exact():
     out = _run("""
 import jax, jax.numpy as jnp, numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
+from repro.launch.mesh import make_test_mesh
 from repro.optim.grad_compression import compressed_psum_tree
-mesh = jax.make_mesh((8,), ("data",))
+mesh = make_test_mesh(8, 1)
 g = jax.random.normal(jax.random.key(0), (8, 64))  # row i on device i
 
 def body(g_loc):
@@ -67,8 +68,8 @@ def body(g_loc):
     return red["w"]
 
 with mesh:
-    got = shard_map(body, mesh=mesh, in_specs=P("data", None),
-                    out_specs=P(None), check_rep=False)(g)
+    got = jax.shard_map(body, mesh=mesh, in_specs=P("data", None),
+                        out_specs=P(None), check_vma=False)(g)
 exact = g.mean(0)
 err = float(jnp.max(jnp.abs(got - exact)))
 scale = float(jnp.max(jnp.abs(g))) / 127.0
@@ -101,12 +102,13 @@ def test_small_mesh_dryrun_end_to_end():
     out = _run("""
 import jax, jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
+from repro.launch.mesh import make_test_mesh
 from repro.models.model_zoo import build_model
 from repro.models.transformer import RunConfig
 from repro.parallel.sharding_rules import AxisRules, tree_specs
 from repro.roofline.analysis import collective_bytes
 from repro.roofline.jaxpr_cost import step_cost
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_test_mesh(2, 4)
 rules = AxisRules.pod()
 rcfg = RunConfig(rules=rules, attn_expand_kv=True, mesh=mesh,
                  q_block=8, kv_block=8)

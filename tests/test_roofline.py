@@ -122,3 +122,23 @@ def test_roofline_terms_dominant():
 def test_cost_addition():
     a = Cost(1.0, 2.0, 0.0) + Cost(3.0, 4.0, 1.0) * 2
     assert a.flops == 7.0 and a.bytes == 10.0
+
+
+def test_cost_analysis_dict_is_empty_only_without_an_analysis():
+    """``{}`` means the backend has no cost analysis; a backend that has
+    one and fails raises instead of silently zeroing the flops feature."""
+    from repro.core.xla_cost import cost_analysis_dict
+
+    class NoAnalysis:            # what JAX reports for such a backend
+        def cost_analysis(self):
+            return None
+
+    class Failing:
+        def cost_analysis(self):
+            raise RuntimeError("cost analysis crashed")
+
+    assert cost_analysis_dict(NoAnalysis()) == {}
+    with pytest.raises(RuntimeError, match="crashed"):
+        cost_analysis_dict(Failing())
+    compiled = jax.jit(lambda x: x @ x).lower(jnp.ones((8, 8))).compile()
+    assert cost_analysis_dict(compiled)["flops"] > 0
