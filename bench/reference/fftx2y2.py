@@ -1,0 +1,5 @@
+"""fftx2y2: magnitude of each image's 2-D FFT."""
+
+
+def kernel(P, c, s):
+    return P.fft2_abs(c["img"])

@@ -1,0 +1,5 @@
+"""fftx4y3: magnitude of each image's 2-D FFT."""
+
+
+def kernel(P, c, s):
+    return P.fft2_abs(c["img"])
