@@ -53,12 +53,6 @@ Works on all the benchmark artifacts:
       never make the fleet slower, regardless of what the shared host
       does to the absolute numbers (both sides of the comparison ride
       the same box in the same run).
-  BENCH_overhead.json (``--serve-real-trace``)  gated on
-      ``python_overhead_fraction`` — coordinator decide+retire wall over
-      total wall in the real-engine replay (lower is better).  A ratio
-      of two times from the same run, so shared-host drift largely
-      cancels; gate it with a loose tolerance anyway — the numerator is
-      small and absolute, not seed-deterministic.
 
 A higher-is-better metric regresses when
 ``fresh < baseline * (1 - tolerance)``; a lower-is-better one when
@@ -102,10 +96,6 @@ GATED_METRICS = {
     "deadline_vs_fifo_violation_improvement":
         ("higher", "fifo / deadline SLO-violation rate on the same "
                    "trace"),
-    "python_overhead_fraction":
-        ("lower", "coordinator (decide+retire) wall over total wall in "
-                  "the real-engine trace replay — same-run ratio, host "
-                  "drift largely cancels"),
     "chaos_crashes":
         ("lower", "scheduler crashes under the committed fault schedule "
                   "(baseline 0 == exact-zero gate: the resilience layer "

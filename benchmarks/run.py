@@ -22,7 +22,6 @@ from __future__ import annotations
 
 import argparse
 import concurrent.futures
-import dataclasses
 import glob
 import json
 import multiprocessing
@@ -39,7 +38,7 @@ sys.path.insert(0, os.path.join(ROOT, "src"))
 # client, hence before the imports below; applies to BOTH engines, so it
 # is a deployment mode, not a thumb on the scale.
 if ("--serve-concurrent" in sys.argv or "--serve-oracle" in sys.argv
-        or "--serve-real-trace" in sys.argv or "--serve-chaos" in sys.argv
+        or "--serve-chaos" in sys.argv
         or "--serve-fleet" in sys.argv):
     os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
                                + " --xla_cpu_multi_thread_eigen=false"
@@ -786,166 +785,6 @@ def serve_latency_trace(*, n_requests: int = 100_000, seed: int = 0,
     return rows
 
 
-REAL_TRACE_PROGRAMS = ["vecadd", "dotprod", "mvmult"]
-
-
-def serve_real_trace(*, n_requests: int = 10_000, seed: int = 0,
-                     window: int = 8, workers: int | None = None,
-                     scale_index: int = 0, backend: str = "host-sync",
-                     profile_alloc: bool = False,
-                     alloc_requests: int = 2_000,
-                     chrome_trace: str | None = None,
-                     metrics_out: str | None = None,
-                     json_path: str = "BENCH_overhead.json") -> list[str]:
-    """Real-engine hot-path profiling: replay a generated 10^4-request
-    trace through the real :class:`ConcurrentScheduler` — kernels
-    executing, wall clock — with span tracing and the metrics registry
-    live, and attribute where the time went.
-
-    This is ROADMAP's real-engine-replay item: the virtual-time harness
-    (``--serve-trace``) answers tail-latency questions at 10^5+ scale,
-    but only a wall-clock run exposes the *scheduler's own* overheads —
-    coordinator Python time per decision, retire-path bookkeeping,
-    hot-path allocations.  The trace reuses :func:`generate_trace`
-    (seeded Poisson arrivals, Zipf workload/tenant skew) restricted to a
-    small program set at one scale, with virtual-epoch arrival stamps
-    cleared so the engine re-stamps them on its own clock.
-
-    Reported to ``BENCH_overhead.json``:
-
-      * per-stage wall attribution (decide/tune/dispatch/retire/refine)
-        from top-level spans;
-      * ``kernel_exec_s`` (sum of measured kernel walls) vs
-        ``wall_s`` — and ``python_overhead_fraction``: coordinator
-        decide+retire wall over total wall, the gated metric (a
-        same-run ratio, so host drift largely cancels);
-      * with ``--profile-alloc``, top allocation sites from a separate,
-        shorter tracemalloc'd pass (tracemalloc ~doubles allocation
-        cost, so the timed pass runs untraced).
-    """
-    from repro.serving import (ConcurrentScheduler, DriftDetector,
-                               HotPathProfiler, MetricsRegistry,
-                               OverlapHeuristicModel, TelemetryLog,
-                               Tracer)
-    from repro.serving.traces import TraceConfig, generate_trace
-
-    workers = workers or max(2, min(window, os.cpu_count() or 2))
-    cfg = TraceConfig(
-        n_requests=n_requests, seed=seed, arrival="poisson",
-        workloads=tuple(REAL_TRACE_PROGRAMS),
-        scale_indices=(scale_index,), churn_prob=0.0,
-        slo_choices=None)
-
-    def requests():
-        reqs = list(generate_trace(cfg))
-        for r in reqs:
-            # generated stamps live on the virtual trace epoch; the real
-            # engine's clock is perf_counter — submit() re-stamps
-            r.arrival_s = None
-        return reqs
-
-    def build(tracer, metrics):
-        # a storm-proof drift threshold: refinements re-profile on a
-        # quiesced pool and would benchmark the refiner, not the
-        # serving hot path
-        return ConcurrentScheduler(
-            OverlapHeuristicModel(), window=window, workers=workers,
-            backend=backend, drift=DriftDetector(threshold=1e9),
-            telemetry=TelemetryLog(), keep_outputs=False,
-            tracer=tracer, metrics=metrics)
-
-    rows = []
-    tracer = Tracer()
-    metrics = MetricsRegistry()
-    sched = build(tracer, metrics)
-    with sched:
-        sched.submit_all(requests())
-        prof = HotPathProfiler(tracer)
-        with prof:
-            results = sched.run()
-        report = prof.report()
-
-    wall = report["wall_s"]
-    stages = report["stages"]
-    kernel_exec_s = sum(r.measured_s for r in results)
-    coord_s = stages["decide"]["wall_s"] + stages["retire"]["wall_s"]
-    overhead_fraction = coord_s / max(wall, 1e-12)
-    rps = len(results) / max(wall, 1e-12)
-
-    rows.append(f"serve_real.window{window}.{backend},"
-                f"{wall / max(len(results), 1) * 1e6:.0f},"
-                f"requests={len(results)},wall_s={wall:.2f},"
-                f"rps={rps:.1f},"
-                f"python_overhead_fraction={overhead_fraction:.4f}")
-    for stage in ("decide", "tune", "dispatch", "retire", "refine"):
-        st = stages[stage]
-        mean_us = (st["mean_s"] * 1e6) if st["mean_s"] is not None else 0
-        rows.append(f"serve_real.stage.{stage},{mean_us:.0f},"
-                    f"wall_s={st['wall_s']:.3f},count={st['count']}")
-
-    allocations = None
-    if profile_alloc:
-        # separate pass: tracemalloc roughly doubles allocation cost, so
-        # the timed numbers above stay clean and this one stays short
-        n_alloc = min(alloc_requests, n_requests)
-        alloc_cfg = dataclasses.replace(cfg, n_requests=n_alloc)
-        tracer2 = Tracer()
-        sched2 = build(tracer2, MetricsRegistry())
-        with sched2:
-            reqs = list(generate_trace(alloc_cfg))
-            for r in reqs:
-                r.arrival_s = None
-            sched2.submit_all(reqs)
-            prof2 = HotPathProfiler(tracer2, alloc=True)
-            with prof2:
-                sched2.run()
-        allocations = prof2.report()["allocations"]
-        for a in allocations[:5]:
-            site = a["site"]
-            if len(site) > 72:
-                site = "..." + site[-69:]
-            rows.append(f"serve_real.alloc,0,site={site},"
-                        f"kb={a['size_kb']:.0f},count={a['count']}")
-
-    if chrome_trace:
-        n_spans = tracer.export_chrome(chrome_trace)
-        rows.append(f"# chrome trace ({n_spans} spans) written to "
-                    f"{chrome_trace}")
-    if metrics_out:
-        metrics.save(metrics_out)
-        rows.append(f"# metrics snapshot written to {metrics_out}")
-
-    payload = {
-        "programs": REAL_TRACE_PROGRAMS,
-        "n_requests": len(results),
-        "seed": seed,
-        "backend": backend,
-        "window": window,
-        "workers": workers,
-        "scale_index": scale_index,
-        "cpu_count": os.cpu_count(),
-        "xla_flags": os.environ.get("XLA_FLAGS", ""),
-        "wall_s": wall,
-        "cpu_s": report["cpu_s"],
-        "throughput_rps": rps,
-        "per_stage_s": stages,
-        "kernel_exec_s": kernel_exec_s,
-        "dispatch_overhead_s": stages["dispatch"]["wall_s"]
-                               - kernel_exec_s,
-        "coordinator_s": coord_s,
-        "python_overhead_fraction": overhead_fraction,
-        "telemetry_summary": sched.telemetry.summary(),
-        "metrics": metrics.snapshot(),
-    }
-    if allocations is not None:
-        payload["allocations"] = allocations
-    os.makedirs(os.path.dirname(os.path.abspath(json_path)), exist_ok=True)
-    with open(json_path, "w") as f:
-        json.dump(payload, f, indent=1)
-    rows.append(f"# overhead JSON written to {json_path}")
-    return rows
-
-
 DEFAULT_FAULT_SCHEDULE = os.path.join(ROOT, "benchmarks", "data",
                                       "chaos_faults.json")
 
@@ -1265,24 +1104,6 @@ def main() -> None:
     ap.add_argument("--trace-requests", type=int, default=100_000,
                     help="requests per generated trace for --serve-trace")
     ap.add_argument("--trace-seed", type=int, default=0)
-    ap.add_argument("--serve-real-trace", action="store_true",
-                    help="replay a generated trace through the REAL "
-                         "concurrent engine (kernels executing, wall "
-                         "clock) with span tracing + metrics live; "
-                         "writes BENCH_overhead.json")
-    ap.add_argument("--real-trace-requests", type=int, default=10_000,
-                    help="requests for --serve-real-trace")
-    ap.add_argument("--real-trace-scale", type=int, default=0,
-                    help="dataset scale index for --serve-real-trace")
-    ap.add_argument("--profile-alloc", action="store_true",
-                    help="--serve-real-trace: add a shorter tracemalloc "
-                         "pass reporting top hot-path allocation sites")
-    ap.add_argument("--chrome-trace", default=None,
-                    help="--serve-real-trace: export the span trace as "
-                         "Chrome trace-event JSON (Perfetto-loadable)")
-    ap.add_argument("--metrics-out", default=None,
-                    help="--serve-real-trace: save the metrics registry "
-                         "snapshot JSON here")
     ap.add_argument("--serve-chaos", action="store_true",
                     help="fault-free vs fault-injected run of the real "
                          "engine with the resilience layer live; writes "
@@ -1345,21 +1166,6 @@ def main() -> None:
                 datasets=args.eval_datasets, reps=args.reps,
                 epochs=args.eval_epochs,
                 json_path=args.serve_json or "BENCH_model.json"):
-            print(row)
-        return
-
-    if args.serve_real_trace:
-        print("name,us_per_call,derived")
-        for row in serve_real_trace(
-                n_requests=args.real_trace_requests,
-                seed=args.trace_seed, window=args.serve_window,
-                workers=args.serve_workers,
-                scale_index=args.real_trace_scale,
-                backend=args.serve_backend,
-                profile_alloc=args.profile_alloc,
-                chrome_trace=args.chrome_trace,
-                metrics_out=args.metrics_out,
-                json_path=args.serve_json or "BENCH_overhead.json"):
             print(row)
         return
 

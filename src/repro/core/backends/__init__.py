@@ -21,14 +21,14 @@ Adding a backend::
 
     class MyBackend(StreamBackend):
         name = "my-backend"
-        def dispatch(self, ctx, config): ...
+        def dispatch(self, ctx, config, *, span=no_span): ...
 
     register_backend(MyBackend())
 """
 from __future__ import annotations
 
 from repro.core.backends.base import (ExecutionContext, StreamBackend,
-                                      dispatch_plan, memoized_jit,
+                                      dispatch_plan, memoized_jit, no_span,
                                       slice_rows, split_arrays)
 from repro.core.backends.host_pipelined import PipelinedHostBackend
 from repro.core.backends.host_sync import SyncHostBackend
@@ -77,7 +77,7 @@ register_backend(MeshBackend())
 
 __all__ = [
     "ExecutionContext", "StreamBackend", "memoized_jit", "split_arrays",
-    "dispatch_plan", "slice_rows", "WindowedPool",
+    "dispatch_plan", "slice_rows", "no_span", "WindowedPool",
     "SyncHostBackend", "PipelinedHostBackend", "ThreadedHostBackend",
     "MeshBackend",
     "register_backend", "get_backend", "list_backends",

@@ -26,6 +26,31 @@ from typing import Any, Callable, Optional
 import jax
 import numpy as np
 
+
+class _NoSpan:
+    """The shared no-op context manager :func:`no_span` hands out."""
+
+    __slots__ = ()
+
+    def __enter__(self) -> "_NoSpan":
+        return self
+
+    def __exit__(self, *exc) -> None:
+        pass
+
+
+NO_SPAN = _NoSpan()
+
+
+def no_span(name: str) -> _NoSpan:
+    """The default span factory of :meth:`StreamBackend.dispatch`: every
+    phase gets the one shared no-op, so an untraced dispatch reads no
+    clock and allocates nothing per task.  A tracing caller passes its
+    own factory instead (the serving scheduler passes ``tracer.span``),
+    called with the phase name alone."""
+    return NO_SPAN
+
+
 # Process-wide jit memo: serving creates one ExecutionContext per request,
 # and a fresh ``jax.jit(kernel)`` wrapper per request would recompile every
 # shape it has already seen.  Workload kernels are module-level callables
@@ -189,9 +214,12 @@ class StreamBackend(abc.ABC):
     #: "runner" (chunkable kernels) or "train-step" (training loops)
     kind: str = "runner"
 
-    def dispatch(self, ctx: ExecutionContext, config) -> list:
+    def dispatch(self, ctx: ExecutionContext, config, *,
+                 span: Callable = no_span) -> list:
         """Issue the full iteration space under ``config``; returns the
-        per-slice outputs (possibly still in flight — callers block)."""
+        per-slice outputs (possibly still in flight — callers block).
+        A backend that blocks on the calling thread before it returns
+        wraps each such wait in ``span("dispatch.wait")``."""
         raise NotImplementedError(f"{self.name} is not a runner backend")
 
     def wrap_train_step(self, loss_fn: Callable, config, *,

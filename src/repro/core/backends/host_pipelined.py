@@ -27,7 +27,7 @@ import warnings
 import jax
 
 from repro.core.backends.base import ExecutionContext, StreamBackend, \
-    dispatch_plan, slice_rows
+    dispatch_plan, no_span, slice_rows
 
 
 class PipelinedHostBackend(StreamBackend):
@@ -38,7 +38,8 @@ class PipelinedHostBackend(StreamBackend):
         assert depth >= 1, depth
         self.depth = depth
 
-    def dispatch(self, ctx: ExecutionContext, config) -> list:
+    def dispatch(self, ctx: ExecutionContext, config, *,
+                 span=no_span) -> list:
         # host-side slicing plan: tasks x partitions, memoized boundaries,
         # each slice a view cut straight from the host arrays
         n_rows = next(iter(ctx.chunked.values())).shape[0]
@@ -74,5 +75,6 @@ class PipelinedHostBackend(StreamBackend):
                 while len(inflight) >= self.depth:
                     # retire the oldest task: bounds live buffers to the
                     # window and (with donation) frees its inputs for reuse
-                    jax.block_until_ready(inflight.popleft())
+                    with span("dispatch.wait"):
+                        jax.block_until_ready(inflight.popleft())
         return outs

@@ -18,14 +18,16 @@ from __future__ import annotations
 import jax
 
 from repro.core.backends.base import ExecutionContext, StreamBackend, \
-    dispatch_plan, slice_rows
+    dispatch_plan, no_span, slice_rows
 
 
 class SyncHostBackend(StreamBackend):
     name = "host-sync"
     kind = "runner"
 
-    def dispatch(self, ctx: ExecutionContext, config) -> list:
+    def dispatch(self, ctx: ExecutionContext, config, *,
+                 span=no_span) -> list:
+        # nothing here blocks: ``span`` has no phase to wrap
         n_rows = next(iter(ctx.chunked.values())).shape[0]
         outs = []
         for parts in dispatch_plan(n_rows, config):

@@ -27,7 +27,7 @@ from typing import Callable, Optional, Sequence
 import jax
 
 from repro.core.backends.base import ExecutionContext, StreamBackend, \
-    dispatch_plan, slice_rows
+    dispatch_plan, no_span, slice_rows
 
 
 class WindowedPool:
@@ -91,7 +91,10 @@ class ThreadedHostBackend(StreamBackend):
         self.workers = workers
         self.window = window
 
-    def dispatch(self, ctx: ExecutionContext, config) -> list:
+    def dispatch(self, ctx: ExecutionContext, config, *,
+                 span=no_span) -> list:
+        # the waits happen on the pool's threads, outside the caller's
+        # spans: ``span`` has no phase to wrap
         n_rows = next(iter(ctx.chunked.values())).shape[0]
         plans = dispatch_plan(n_rows, config)
 

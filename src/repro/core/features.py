@@ -78,13 +78,33 @@ class RawFeatures:
 
 def extract_features(runner: StreamedRunner, *, profile: bool = True,
                      profile_reps: int = 2) -> RawFeatures:
+    """All 22 raw features: the static ones, as :func:`static_features`
+    gives them, then :func:`profiled_features` (zeros, and a ratio of 0,
+    without ``profile``)."""
+    static = _static_values(runner, runner.lowered_kernel())
+    dynamic = (profiled_features(runner, reps=profile_reps) if profile
+               else _dynamic_values(0.0, 0.0, 0.0))
+    return RawFeatures(np.concatenate((static, dynamic)))
+
+
+def static_features(runner: StreamedRunner) -> np.ndarray:
+    """The 18 static features: the transfer structure, and the compiled
+    single-chunk kernel's cost analysis and op mix (a lower, a compile
+    or a compile-cache load, ``cost_analysis`` and a scan of the HLO
+    text).  Nothing runs on the device."""
+    # lowered here and in extract_features, at the same call depth: the
+    # HLO text's location tables hold the lowering caller's stack, and
+    # ``hlo_ops`` counts their lines
+    return _static_values(runner, runner.lowered_kernel())
+
+
+def _static_values(runner: StreamedRunner, lowered) -> np.ndarray:
     wl, chunked, shared = runner.wl, runner.chunked, runner.shared
     rows = next(iter(chunked.values())).shape[0]
     loop_nest = max(a.ndim for a in chunked.values())
     dts = _tree_bytes(chunked) + _tree_bytes(shared)
     red = _tree_bytes(shared)
 
-    lowered = runner.lowered_kernel()
     compiled = lowered.compile()
     cost = cost_analysis_dict(compiled)  # {} on backends without analysis
     flops = float(cost.get("flops", 0.0))
@@ -102,15 +122,7 @@ def extract_features(runner: StreamedRunner, *, profile: bool = True,
     n_gs = len(_GATHER.findall(joined))
 
     out_shapes = _output_bytes(wl, chunked, shared)
-    if profile:
-        t_xfer = runner.measure_transfer(reps=profile_reps)
-        t_comp = runner.measure_compute(reps=profile_reps)
-        t_single = runner.run_single_stream(reps=profile_reps)
-    else:
-        t_xfer = t_comp = t_single = 0.0
-    ratio = math.log(max(t_comp, 1e-9) / max(t_xfer, 1e-9))
-
-    vals = np.array([
+    return np.array([
         loop_nest,
         rows,
         _tree_count(chunked) + _tree_count(shared),
@@ -129,12 +141,24 @@ def extract_features(runner: StreamedRunner, *, profile: bool = True,
         n_trans,
         n_gs,
         1.0 if wl.sequential_inner else 0.0,
-        t_single * 1e6,
-        t_xfer * 1e6,
-        t_comp * 1e6,
-        ratio,
     ], dtype=np.float64)
-    return RawFeatures(vals)
+
+
+def profiled_features(runner: StreamedRunner, *, reps: int = 2) -> np.ndarray:
+    """The 4 dynamic features, from three measurements on the device:
+    the H2D transfer, the kernel alone and a single-stream run (the
+    last two each warm up first)."""
+    t_xfer = runner.measure_transfer(reps=reps)
+    t_comp = runner.measure_compute(reps=reps)
+    t_single = runner.run_single_stream(reps=reps)
+    return _dynamic_values(t_single, t_xfer, t_comp)
+
+
+def _dynamic_values(t_single: float, t_xfer: float,
+                    t_comp: float) -> np.ndarray:
+    ratio = math.log(max(t_comp, 1e-9) / max(t_xfer, 1e-9))
+    return np.array([t_single * 1e6, t_xfer * 1e6, t_comp * 1e6, ratio],
+                    dtype=np.float64)
 
 
 def _output_bytes(wl: Workload, chunked: dict, shared: dict) -> float:

@@ -18,7 +18,7 @@ import jax
 import numpy as np
 
 from repro.core.backends import (StreamBackend, ExecutionContext,
-                                 get_backend, split_arrays)
+                                 get_backend, no_span, split_arrays)
 from repro.core.stream_config import SINGLE_STREAM, StreamConfig
 from repro.core.workloads import Workload
 
@@ -69,10 +69,16 @@ class StreamedRunner:
 
     # -- execution -----------------------------------------------------------
 
-    def dispatch(self, config: StreamConfig) -> list:
+    def dispatch(self, config: StreamConfig, *,
+                 span: Callable = no_span) -> list:
         """Issue the full iteration space under ``config``; returns the
-        per-slice outputs (possibly still in flight — callers block)."""
-        return self.backend.dispatch(self.ctx, config)
+        per-slice outputs (possibly still in flight — callers block).
+        ``span`` is the backend's phase-span factory; untraced, the
+        backend is called without it, so a backend written to the
+        two-argument form keeps working."""
+        if span is no_span:
+            return self.backend.dispatch(self.ctx, config)
+        return self.backend.dispatch(self.ctx, config, span=span)
 
     # legacy private name, used by older tests
     _dispatch = dispatch

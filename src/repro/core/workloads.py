@@ -16,6 +16,7 @@ exactly 39 programs).
 from __future__ import annotations
 
 import dataclasses
+import types
 from typing import Callable
 
 import jax
@@ -43,9 +44,24 @@ _REGISTRY: dict[str, Workload] = {}
 
 
 def register(wl: Workload) -> Workload:
+    """Register ``wl`` with its kernel named after the program, so that
+    the kernel's jitted programs carry the name (``jit_<program>``) in
+    compiled modules and device traces."""
     assert wl.name not in _REGISTRY
+    wl = dataclasses.replace(wl, kernel=_named(wl.kernel, wl.name))
     _REGISTRY[wl.name] = wl
     return wl
+
+
+def _named(fn: Callable, name: str) -> Callable:
+    """A copy of ``fn`` (same code, globals, defaults and closure) named
+    ``name``: a copy, because one kernel function may serve several
+    programs (the three FFT variants)."""
+    named = types.FunctionType(fn.__code__, fn.__globals__, name,
+                               fn.__defaults__, fn.__closure__)
+    named.__kwdefaults__ = fn.__kwdefaults__
+    named.__qualname__ = name
+    return named
 
 
 def get_workload(name: str) -> Workload:

@@ -39,9 +39,8 @@ from repro.serving.engine import (ConcurrentScheduler, ContextPool,
 from repro.serving.fleet import (FleetRouter, WorkerConfig, fleet_summary,
                                  merge_metrics, merge_samples, shard_for)
 from repro.serving.observability import (NULL_METRICS, NULL_TRACER,
-                                         HotPathProfiler, MetricsRegistry,
-                                         NullMetrics, NullTracer, Tracer,
-                                         aggregate_stage_times)
+                                         MetricsRegistry, NullMetrics,
+                                         NullTracer, Tracer)
 from repro.serving.queue import POLICIES, RequestQueue, WorkloadRequest
 from repro.serving.refinement import (DriftDetector, RefinementResult,
                                       Refiner, contention_factor)
@@ -76,7 +75,6 @@ __all__ = [
     "TenantContext", "TenantRegistry",
     "Tracer", "NullTracer", "NULL_TRACER",
     "MetricsRegistry", "NullMetrics", "NULL_METRICS",
-    "HotPathProfiler", "aggregate_stage_times",
     "BreakerConfig", "CircuitBreaker", "FaultPlan", "FaultSpec",
     "InjectedFault", "NULL_FAULTS", "ResiliencePolicy", "RetryPolicy",
     "atomic_write_json", "call_with_retry", "corrupt_json_file",

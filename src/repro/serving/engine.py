@@ -409,12 +409,17 @@ class ConcurrentScheduler(AdaptiveScheduler):
         def budget_left() -> bool:
             return max_requests is None or decided < max_requests
 
+        def drain(why: str) -> Optional[BaseException]:
+            # the coordinator blocks here until the pool is empty
+            with self.tracer.span("engine.drain", why=why):
+                return self._drain(inflight, results)
+
         def check(error: Optional[BaseException]) -> None:
             if error is not None:
                 # finish the survivors cleanly, then surface the failure;
                 # queued refinements are abandoned (their runners still
                 # go back to the pool), not profiled mid-error
-                self._drain(inflight, results)
+                drain("error")
                 for p, *_ in self._deferred_refinements:
                     self._release_runner(p.runner)
                 self._deferred_refinements.clear()
@@ -426,7 +431,7 @@ class ConcurrentScheduler(AdaptiveScheduler):
             # idle and (b) the decisions below see the refreshed cache
             # entry — the same visibility inline refinement had
             if self._deferred_refinements:
-                check(self._drain(inflight, results))
+                check(drain("refine"))
                 self._flush_refinements()
             # decide: fill the free window slots in queue-policy order
             batch: list[PendingRequest] = []
@@ -453,7 +458,7 @@ class ConcurrentScheduler(AdaptiveScheduler):
             colds = [p for p in batch if p.entry is None]
             anchors = [p for p in batch if p.needs_anchor]
             if colds or anchors:
-                check(self._drain(inflight, results))
+                check(drain("cold" if colds else "anchor"))
             for p in anchors:
                 if self.resilience is None:
                     self._measure_anchor(p)

@@ -1,20 +1,18 @@
-"""End-to-end serving observability: span tracing, a metrics registry,
-and hot-path profiling.
+"""End-to-end serving observability: span tracing and a metrics
+registry.
 
-Three planes, one clock:
+Two planes, one clock:
 
   * :mod:`~repro.serving.observability.tracing` — per-request nested
     spans (``decide`` → ``tune`` → ``dispatch`` → ``retire`` →
-    ``refine``) stamped from the owning scheduler's injected clock, so
-    the virtual-clock trace harness and the real concurrent engine
-    share one instrumentation path; exported as JSONL or Chrome
-    trace-event JSON (Perfetto-loadable).
+    ``refine``, the phases inside them, and the engine's pool drains)
+    stamped from the owning scheduler's injected clock, so the
+    virtual-clock trace harness and the real concurrent engine share one
+    instrumentation path; exported as JSONL or Chrome trace-event JSON
+    (Perfetto-loadable), and mirrored into any ``jax.profiler`` capture.
   * :mod:`~repro.serving.observability.metrics` — process-wide named
     counters / gauges / histograms with deterministic ``snapshot()``
     and a Prometheus text exporter.
-  * :mod:`~repro.serving.observability.profiling` — opt-in tracemalloc
-    allocation profiling plus per-stage wall/CPU aggregation; feeds
-    ``benchmarks/run.py --serve-real-trace`` → ``BENCH_overhead.json``.
 
 Everything defaults off: the schedulers ship with :data:`NULL_TRACER` /
 :data:`NULL_METRICS`, whose hot-path operations are shared no-op
@@ -26,9 +24,6 @@ from repro.serving.observability.metrics import (DEFAULT_BUCKETS,
                                                  MetricsRegistry,
                                                  NULL_METRICS,
                                                  NullMetrics)
-from repro.serving.observability.profiling import (AllocationProfiler,
-                                                   HotPathProfiler,
-                                                   aggregate_stage_times)
 from repro.serving.observability.tracing import (NULL_TRACER, STAGES,
                                                  NullTracer, SpanRecord,
                                                  Tracer, stage_of)
@@ -38,5 +33,4 @@ __all__ = [
     "stage_of",
     "MetricsRegistry", "NullMetrics", "NULL_METRICS",
     "Counter", "Gauge", "Histogram", "DEFAULT_BUCKETS",
-    "AllocationProfiler", "HotPathProfiler", "aggregate_stage_times",
 ]

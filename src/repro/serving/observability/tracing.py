@@ -1,7 +1,8 @@
 """Request span tracing for the serving stack.
 
 One :class:`Tracer` instance per scheduler records nested, named spans —
-``decide``, ``tune.cold.batch``, ``dispatch``, ``retire``, ``refine`` —
+``decide``, ``tune.cold.batch``, ``dispatch``, ``retire``, ``refine``
+and the phases inside them (``dispatch.issue``, ``tune.static``, ...) —
 each stamped from the *scheduler's own clock* (the tracer binds to the
 injected clock at scheduler construction), so span timestamps, telemetry
 latency stamps, and drift-window judgments can never disagree, and the
@@ -24,9 +25,15 @@ Exports: ``export_jsonl`` (one span per line, greppable) and
 microsecond timestamps rebased to the trace start, one Perfetto track
 per recording thread.
 
+Every live span of an enabled tracer also enters a
+``jax.profiler.TraceAnnotation`` of its name, entered before and exited
+after the span's own clock reads, so a ``jax.profiler`` capture shows
+the program's spans on the profiler's clock beside the device's ops.
+The records keep the bound clock.
+
 The disabled path must cost nothing: :data:`NULL_TRACER` is a process
 singleton whose ``span()`` returns one shared no-op context manager —
-no clock read, no allocation, no lock — so schedulers constructed
+no clock read, no annotation, no lock — so schedulers constructed
 without a tracer (the default) keep their pre-observability hot path.
 """
 from __future__ import annotations
@@ -38,8 +45,13 @@ import threading
 import time
 from typing import Optional
 
+from jax.profiler import TraceAnnotation
+
+from repro.core.backends.base import NO_SPAN
+
 #: span name prefix -> attribution stage; ``stage_of("tune.cold.batch")``
-#: is ``"tune"`` — the five-way split BENCH_overhead.json reports
+#: is ``"tune"`` — the five stages the scheduler's
+#: ``serving.stage.<stage>.seconds`` histograms observe
 STAGES = ("decide", "tune", "dispatch", "retire", "refine")
 
 
@@ -52,9 +64,7 @@ def stage_of(name: str) -> str:
 @dataclasses.dataclass
 class SpanRecord:
     """One closed span.  ``t_start``/``t_end`` are seconds on the
-    tracer's bound clock; ``cpu_s`` is thread CPU time consumed inside
-    the span (None when the tracer was built with ``cpu=False`` or the
-    span came from ``record()``)."""
+    tracer's bound clock."""
 
     name: str
     t_start: float
@@ -63,7 +73,6 @@ class SpanRecord:
     trace_id: Optional[str] = None  # request correlation id
     parent: Optional[str] = None    # enclosing span's name (same thread)
     depth: int = 0                  # nesting depth on its thread
-    cpu_s: Optional[float] = None
     attrs: Optional[dict] = None
 
     @property
@@ -79,8 +88,6 @@ class SpanRecord:
             d["parent"] = self.parent
         if self.depth:
             d["depth"] = self.depth
-        if self.cpu_s is not None:
-            d["cpu_s"] = self.cpu_s
         if self.attrs:
             d["attrs"] = self.attrs
         return d
@@ -88,11 +95,12 @@ class SpanRecord:
 
 class _SpanCM:
     """A live span.  Created per ``span()`` call on an enabled tracer;
-    enter stamps the clock (and optionally thread CPU time), exit closes
-    the record and appends it to the tracer under its lock."""
+    enter opens the profiler annotation and stamps the clock, exit
+    stamps the clock, closes the annotation and appends the record to
+    the tracer under its lock."""
 
     __slots__ = ("tracer", "name", "trace_id", "attrs",
-                 "_t0", "_cpu0", "_frame")
+                 "_t0", "_frame", "_ann")
 
     def __init__(self, tracer: "Tracer", name: str,
                  trace_id: Optional[str], attrs: Optional[dict]):
@@ -105,14 +113,14 @@ class _SpanCM:
         stack = self.tracer._stack()
         self._frame = (self.name, len(stack))
         stack.append(self.name)
-        self._cpu0 = time.thread_time() if self.tracer.cpu else None
+        self._ann = TraceAnnotation(self.name)
+        self._ann.__enter__()
         self._t0 = self.tracer.now()
         return self
 
     def __exit__(self, *exc) -> None:
         t1 = self.tracer.now()
-        cpu = (time.thread_time() - self._cpu0
-               if self._cpu0 is not None else None)
+        self._ann.__exit__(None, None, None)
         stack = self.tracer._stack()
         stack.pop()
         name, depth = self._frame
@@ -121,22 +129,7 @@ class _SpanCM:
             tid=self.tracer._tid(),
             trace_id=self.trace_id,
             parent=stack[-1] if stack else None,
-            depth=depth, cpu_s=cpu, attrs=self.attrs))
-
-
-class _NullSpan:
-    """The shared no-op span: zero clock reads, zero allocation."""
-
-    __slots__ = ()
-
-    def __enter__(self) -> "_NullSpan":
-        return self
-
-    def __exit__(self, *exc) -> None:
-        pass
-
-
-_NULL_SPAN = _NullSpan()
+            depth=depth, attrs=self.attrs))
 
 
 class Tracer:
@@ -146,17 +139,12 @@ class Tracer:
     to have the owning scheduler bind its own clock at construction
     (the recommended wiring — one time source per scheduler).  An
     unbound tracer used standalone falls back to ``time.perf_counter``.
-
-    ``cpu=True`` additionally records per-span *thread* CPU time
-    (``time.thread_time``), the wall-vs-CPU split the hot-path profiler
-    attributes Python overhead with.
     """
 
     enabled = True
 
-    def __init__(self, clock=None, *, cpu: bool = False):
+    def __init__(self, clock=None):
         self.clock = clock
-        self.cpu = cpu
         self.spans: list[SpanRecord] = []
         self._lock = threading.Lock()
         self._local = threading.local()
@@ -260,8 +248,9 @@ class NullTracer:
         self.clock = None
         self.spans: list = []
 
-    def span(self, name: str, *, trace_id=None, **attrs) -> _NullSpan:
-        return _NULL_SPAN
+    def span(self, name: str, *, trace_id=None, **attrs):
+        # the executor's shared no-op: zero clock reads, zero allocation
+        return NO_SPAN
 
     def record(self, *a, **k) -> None:
         pass

@@ -31,7 +31,7 @@ import numpy as np
 
 from repro.core import features as feat_lib
 from repro.core.autotuner import TuneResult, TuningCache
-from repro.core.backends import get_backend
+from repro.core.backends import get_backend, no_span
 from repro.core.features import RAW_FEATURE_NAMES
 # re-exported for back-compat: the heuristic used to be defined here
 from repro.core.modeling.heuristic import OverlapHeuristicModel  # noqa: F401
@@ -352,11 +352,16 @@ class AdaptiveScheduler:
                 if ok] or [SINGLE_STREAM]
 
     def _extract(self, pending: PendingRequest) -> np.ndarray:
-        feats = feat_lib.extract_features(pending.runner, profile_reps=1)
-        self._feats[pending.key] = feats.values
-        self._t_single[pending.key] = \
-            float(feats.values[_I_T_SINGLE]) * 1e-6
-        return feats.values
+        """``extract_features(runner, profile_reps=1)``, its two halves
+        under spans of their own."""
+        with self.tracer.span("tune.static"):
+            static = feat_lib.static_features(pending.runner)
+        with self.tracer.span("tune.profile"):
+            dynamic = feat_lib.profiled_features(pending.runner, reps=1)
+        values = np.concatenate((static, dynamic))
+        self._feats[pending.key] = values
+        self._t_single[pending.key] = float(values[_I_T_SINGLE]) * 1e-6
+        return values
 
     def _model_for(self, pending: PendingRequest):
         """The model that ranks configs for this request: the tenant's
@@ -627,27 +632,39 @@ class AdaptiveScheduler:
         only shared state is the ``_warmed`` set (GIL-atomic adds; a rare
         duplicate warmup is harmless).  First occurrence of a
         (bucket, config) pair warms up so measured runtime is execution,
-        not compilation."""
+        not compilation.
+
+        Phases, each a span nested in ``dispatch``: ``dispatch.warmup``,
+        ``dispatch.issue`` (host slicing, H2D and kernel enqueue, with the
+        backend's own window waits nested as ``dispatch.wait``), the
+        final ``dispatch.wait`` and ``dispatch.read``.  Untraced, the
+        backend gets the no-op factory, so its per-task loop reads no
+        clock and allocates nothing."""
         runner, key = pending.runner, pending.key
         pending.t_dispatch_s = self.clock.now()
         config = pending.entry.config
+        span = self.tracer.span if self.tracer.enabled else no_span
         with self.tracer.span("dispatch", trace_id=pending.req.trace_id,
                               partitions=config.partitions,
                               tasks=config.tasks):
             self.faults.fire("dispatch")
             if self.warm_before_measure and \
                     (key, config) not in self._warmed:
-                runner.warmup(config)
+                with span("dispatch.warmup"):
+                    runner.warmup(config)
                 self._warmed.add((key, config))
             t0 = self.clock.now()
-            outs = runner.dispatch(config)
-            jax.block_until_ready(outs)
+            with span("dispatch.issue"):
+                outs = runner.dispatch(config, span=span)
+            with span("dispatch.wait"):
+                jax.block_until_ready(outs)
             # read back like StreamedRunner.run does — every output leaf
             # — so measured_s and the single-stream prediction anchor are
             # timed on the same basis (dispatch + compute + D2H);
             # otherwise rel_error carries a constant bias on
             # transfer-heavy workloads
-            readback_outputs(outs)
+            with span("dispatch.read"):
+                readback_outputs(outs)
             measured_s = self.clock.now() - t0
         self._m_stage["dispatch"].observe(measured_s)
         return outs, measured_s

@@ -52,3 +52,26 @@ def test_sequential_flag():
     f = extract_features(StreamedRunner(wl, chunked, shared),
                          profile=False).as_dict()
     assert f["sequential_inner"] == 1.0
+
+
+@pytest.mark.parametrize("name", ["mvmult", "binomial", "fftx4y3"])
+def test_static_and_profiled_features_compose_to_extract_features(
+        name, monkeypatch):
+    """The scheduler's two halves give extract_features' vector, bit for
+    bit (the device timings fixed, as they differ run to run)."""
+    from repro.core.features import profiled_features, static_features
+
+    wl = get_workload(name)
+    chunked, shared = wl.make_data(wl.datasets[0],
+                                   np.random.default_rng(0))
+    runner = StreamedRunner(wl, chunked, shared)
+    for attr, t in (("measure_transfer", 3e-4), ("measure_compute", 2e-4),
+                    ("run_single_stream", 7e-4)):
+        monkeypatch.setattr(runner, attr, lambda reps, t=t: t)
+    whole = extract_features(runner, profile_reps=1).values
+    parts = np.concatenate((static_features(runner),
+                            profiled_features(runner, reps=1)))
+    assert parts.dtype == whole.dtype and parts.tobytes() == whole.tobytes()
+    unprofiled = extract_features(runner, profile=False).values
+    assert unprofiled[:18].tobytes() == static_features(runner).tobytes()
+    assert not unprofiled[18:].any()

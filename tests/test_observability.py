@@ -1,5 +1,5 @@
-"""End-to-end observability: span tracing, the metrics registry, and
-hot-path profiling (repro.serving.observability).
+"""End-to-end observability: span tracing and the metrics registry
+(repro.serving.observability).
 
 Covers the PR's acceptance bars: spans nest correctly under a virtual
 clock, trace IDs survive the concurrent engine's out-of-order
@@ -9,18 +9,23 @@ path is a shared no-op singleton (zero per-call allocation), the
 empty-window telemetry contract (typed raise at the primitive, None at
 the aggregators), and one-clock plumbing across queue / scheduler /
 refiner / tracer."""
+import collections
 import json
+import time
 
 import pytest
 
+from repro.core.backends import no_span
+from repro.core.backends.base import NO_SPAN
+from repro.core.stream_config import StreamConfig
 from repro.serving import (AdaptiveScheduler, ConcurrentScheduler,
                            NULL_METRICS, NULL_TRACER, MetricsRegistry,
                            OverlapHeuristicModel, TelemetryLog, Tracer,
-                           aggregate_stage_times, make_trace)
+                           make_trace)
 from repro.serving.clock import VirtualClock
 from repro.serving.observability.metrics import (_NULL_INSTRUMENT,
                                                  Histogram)
-from repro.serving.observability.tracing import _NULL_SPAN, stage_of
+from repro.serving.observability.tracing import stage_of
 from repro.serving.telemetry import (EmptyWindowError, TelemetrySample,
                                      latency_stats, percentile)
 from repro.serving.traces import TraceConfig, generate_trace, \
@@ -58,20 +63,6 @@ def test_stage_of_rollup():
     assert stage_of("tune.cold.batch") == "tune"
     assert stage_of("decide") == "decide"
     assert stage_of("custom") == "custom"
-
-
-def test_aggregate_skips_nested_spans():
-    tr = Tracer(VirtualClock())
-    tr.record("retire", 0.0, 3.0, trace_id="a")
-    tr.record("refine", 1.0, 2.0, trace_id="a")       # depth 0 by record
-    with tr.span("decide"):
-        with tr.span("tune.cold"):                    # depth 1: excluded
-            pass
-    agg = aggregate_stage_times(tr.spans)
-    assert agg["retire"]["wall_s"] == pytest.approx(3.0)
-    assert agg["refine"]["count"] == 1
-    assert agg["tune"]["count"] == 0                  # nested, skipped
-    assert agg["dispatch"] == {"wall_s": 0.0, "count": 0, "mean_s": None}
 
 
 def test_trace_ids_survive_out_of_order_retirement():
@@ -131,7 +122,7 @@ def test_null_tracer_is_shared_noop():
     # no clock reads — schedulers built without a tracer pay ~nothing
     s1 = NULL_TRACER.span("decide", trace_id="r000000", tenant="a")
     s2 = NULL_TRACER.span("dispatch")
-    assert s1 is s2 is _NULL_SPAN
+    assert s1 is s2 is NO_SPAN
     with s1:
         pass
     NULL_TRACER.record("retire", 0.0, 1.0)
@@ -144,6 +135,214 @@ def test_scheduler_never_mutates_null_singletons():
     assert sched.tracer is NULL_TRACER
     assert sched.metrics is NULL_METRICS
     assert NULL_TRACER.clock is None       # bind-my-clock must not leak
+
+
+# -- phase spans: dispatch, cold tunes, pool drains --------------------------
+
+
+class _Annotations:
+    """Stands in for ``jax.profiler.TraceAnnotation``: logs each enter
+    and exit by name."""
+
+    def __init__(self, log):
+        self.log = log
+
+    def __call__(self, name):
+        log = self.log
+
+        class _Ann:
+            def __enter__(self):
+                log.append(("enter", name))
+
+            def __exit__(self, *exc):
+                log.append(("exit", name))
+
+        return _Ann()
+
+
+class _CountingClock:
+    def __init__(self, log=None):
+        self.reads = 0
+        self.log = log
+
+    def now(self):
+        self.reads += 1
+        if self.log is not None:
+            self.log.append(("now", None))
+        return time.perf_counter()
+
+
+#: one split with more tasks than host-pipelined's window of 2, so the
+#: backend retires tasks inside its own dispatch
+SPLIT = StreamConfig(1, 4)
+
+
+def _engine(tracer, window, **kw):
+    return ConcurrentScheduler(
+        OverlapHeuristicModel(), window=window, backend="host-pipelined",
+        candidates=[SPLIT], tracer=tracer, telemetry=TelemetryLog(),
+        keep_outputs=False, **kw)
+
+
+def _children(spans, outer):
+    """The spans nested directly in ``outer``: same thread, one level
+    deeper, inside its interval."""
+    return [s for s in spans if s.tid == outer.tid
+            and s.depth == outer.depth + 1
+            and outer.t_start <= s.t_start <= s.t_end <= outer.t_end]
+
+
+def test_dispatch_phases_nest_in_each_dispatch_span():
+    tr = Tracer()
+    programs = ["vecadd", "dotprod", "mvmult", "scalarprod"]
+    with _engine(tr, window=3) as sched:
+        sched.submit_all(make_trace(programs, occurrences=1))
+        results = sched.run()
+    assert [r.config for r in results] == [SPLIT] * len(programs)
+    coord = {s.tid for s in tr.spans if s.name == "decide"}
+    dispatches = [s for s in tr.spans if s.name == "dispatch"]
+    assert len(dispatches) == len(programs)
+    for d in dispatches:
+        assert d.tid not in coord and d.parent is None
+        kids = _children(tr.spans, d)
+        assert all(k.parent == "dispatch" for k in kids)
+        names = collections.Counter(k.name for k in kids)
+        # every key is new: one warm-up, then issue, wait and read
+        assert names == {"dispatch.warmup": 1, "dispatch.issue": 1,
+                         "dispatch.wait": 1, "dispatch.read": 1}
+        order = [k.name for k in sorted(kids, key=lambda k: k.t_start)]
+        assert order == ["dispatch.warmup", "dispatch.issue",
+                         "dispatch.wait", "dispatch.read"]
+        (issue,) = [k for k in kids if k.name == "dispatch.issue"]
+        window_waits = _children(tr.spans, issue)
+        # 4 tasks through a window of 2: tasks 1-3 retire inside issue
+        assert [w.name for w in window_waits] == ["dispatch.wait"] * 3
+        assert all(w.parent == "dispatch.issue" for w in window_waits)
+    # phases are spans of their own: one retire per request still
+    names = collections.Counter(s.name for s in tr.spans)
+    assert names["retire"] == len(programs)
+
+
+def test_dispatch_warmup_only_on_first_dispatch_of_a_key():
+    tr = Tracer()
+    with _engine(tr, window=1) as sched:
+        sched.submit_all(make_trace(["vecadd", "mvmult"], occurrences=3,
+                                    tenants=("a",)))
+        results = sched.run()
+    program = {r.request.trace_id: r.request.workload for r in results}
+    seen = set()
+    for d in sorted((s for s in tr.spans if s.name == "dispatch"),
+                    key=lambda s: s.t_start):
+        # one tenant, one shape a program: the program names the key,
+        # and a drift refinement may change the split
+        key = (program[d.trace_id], d.attrs["partitions"], d.attrs["tasks"])
+        warm = [k for k in _children(tr.spans, d)
+                if k.name == "dispatch.warmup"]
+        assert len(warm) == (key not in seen), key
+        seen.add(key)
+    assert len(seen) < len(results)
+
+
+def test_cold_tunes_hold_one_static_and_one_profile_per_bucket():
+    tr = Tracer()
+    programs = ["vecadd", "dotprod", "mvmult"]
+    with _engine(tr, window=4) as sched:
+        sched.submit_all(make_trace(programs, occurrences=1))
+        sched.run()
+    (batch,) = [s for s in tr.spans if s.name == "tune.cold.batch"]
+    assert batch.attrs["buckets"] == len(programs)
+    kids = collections.Counter(k.name for k in _children(tr.spans, batch))
+    assert kids == {"tune.static": 3, "tune.profile": 3}
+    # the serial scheduler's one-bucket tune holds one of each
+    tr = Tracer()
+    with _sched(tracer=tr) as sched:
+        sched.submit_all(make_trace(["vecadd"], occurrences=1))
+        sched.run()
+    (cold,) = [s for s in tr.spans if s.name == "tune.cold"]
+    kids = _children(tr.spans, cold)
+    assert [k.name for k in kids] == ["tune.static", "tune.profile"]
+    assert all(k.parent == "tune.cold" for k in kids)
+
+
+def test_cold_wave_opens_engine_drain_on_the_coordinator():
+    tr = Tracer()
+    with _engine(tr, window=2) as sched:
+        sched.submit_all(make_trace(["vecadd", "dotprod", "mvmult"],
+                                    occurrences=1))
+        sched.run()
+    coord = {s.tid for s in tr.spans if s.name == "decide"}
+    drains = [s for s in tr.spans if s.name == "engine.drain"]
+    # two waves, each holding a cold request
+    assert [d.attrs for d in drains] == [{"why": "cold"}] * 2
+    assert {d.tid for d in drains} == coord and len(coord) == 1
+    assert all(d.parent is None for d in drains)
+    # a warm hit persisted by another process drains for its anchor
+    cache = sched.cache
+    tr = Tracer()
+    with _engine(tr, window=2, cache=cache) as sched:
+        sched.submit_all(make_trace(["vecadd"], occurrences=1))
+        sched.run()
+    assert [s.attrs for s in tr.spans if s.name == "engine.drain"] \
+        == [{"why": "anchor"}]
+
+
+def test_null_tracer_dispatch_reads_no_clock_and_enters_no_annotation(
+        monkeypatch):
+    from repro.serving.observability import tracing
+
+    log = []
+    monkeypatch.setattr(tracing, "TraceAnnotation", _Annotations(log))
+
+    def one_dispatch(tracer):
+        clock = _CountingClock()
+        sched = AdaptiveScheduler(
+            OverlapHeuristicModel(), backend="host-pipelined",
+            candidates=[SPLIT], clock=clock, tracer=tracer,
+            telemetry=TelemetryLog(), keep_outputs=False)
+        (req,) = make_trace(["vecadd"], occurrences=1)
+        sched.submit(req)
+        pending = sched._decide(sched.queue.pop())
+        sched._tune_cold(pending)
+        factories = []
+        dispatch = pending.runner.dispatch
+
+        def spy(config, *, span=no_span):
+            factories.append(span)
+            return dispatch(config, span=span)
+
+        pending.runner.dispatch = spy
+        before = clock.reads
+        sched._execute(pending)
+        return clock.reads - before, factories
+
+    del log[:]
+    reads, factories = one_dispatch(NULL_TRACER)
+    # the scheduler's own stamps (dispatch time, measured start and end)
+    # and nothing else; the backend gets the shared no-op factory
+    assert reads == 3 and log == []
+    assert factories == [no_span] and no_span("dispatch.wait") is NO_SPAN
+
+    reads, factories = one_dispatch(Tracer())
+    assert reads > 3 and log
+    assert factories[0] is not no_span
+
+
+def test_mirror_enters_and_exits_one_annotation_per_span(monkeypatch):
+    from repro.serving.observability import tracing
+
+    log = []
+    monkeypatch.setattr(tracing, "TraceAnnotation", _Annotations(log))
+    tr = Tracer(_CountingClock(log))
+    with tr.span("dispatch", trace_id="r000000", tasks=4):
+        with tr.span("dispatch.issue"):
+            pass
+    # each annotation opens before its span's first clock read and
+    # closes after its last, nested as the spans are
+    assert log == [("enter", "dispatch"), ("now", None),
+                   ("enter", "dispatch.issue"), ("now", None),
+                   ("now", None), ("exit", "dispatch.issue"),
+                   ("now", None), ("exit", "dispatch")]
+    assert [s.name for s in tr.spans] == ["dispatch.issue", "dispatch"]
 
 
 # -- metrics registry --------------------------------------------------------
@@ -313,10 +512,8 @@ def test_serial_scheduler_metrics_and_spans_consistent():
     for stage in ("decide", "dispatch", "retire"):
         assert val(f"serving.stage.{stage}.seconds")["count"] == n
     # one top-level decide/dispatch/retire span per request
-    by_stage = aggregate_stage_times(tr.spans)
-    assert by_stage["decide"]["count"] == n
-    assert by_stage["dispatch"]["count"] == n
-    assert by_stage["retire"]["count"] == n
+    top = collections.Counter(s.name for s in tr.spans if s.depth == 0)
+    assert top["decide"] == top["dispatch"] == top["retire"] == n
     # telemetry carries the queue-assigned ids
     assert all(s.trace_id is not None for s in sched.telemetry)
 

@@ -63,3 +63,18 @@ def test_sum_partials(name):
     parts = (np.asarray(jax.jit(wl.kernel)(a, shared))
              + np.asarray(jax.jit(wl.kernel)(b, shared)))
     np.testing.assert_allclose(parts, full, rtol=1e-3)
+
+
+def test_kernels_are_named_after_their_programs():
+    """A compiled module (and so a device trace) names its program; the
+    three FFT variants share one kernel body but not its name."""
+    for name in list_workloads():
+        wl = get_workload(name)
+        assert wl.kernel.__name__ == wl.kernel.__qualname__ == name
+    ffts = [get_workload(n).kernel for n in ("fftx1y1", "fftx2y2", "fftx4y3")]
+    assert len({id(k) for k in ffts}) == 3
+    assert len({k.__code__ for k in ffts}) == 1
+    wl = get_workload("jacobi-2d")
+    chunked, shared = wl.make_data(wl.datasets[0], np.random.default_rng(0))
+    hlo = jax.jit(wl.kernel).lower(chunked, shared).as_text()
+    assert "jit_jacobi-2d" in hlo.splitlines()[0]
