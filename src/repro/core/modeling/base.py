@@ -10,6 +10,9 @@ models through the same small surface:
       layout (program features ++ config encoding);
   ``refit(X, y)``       *optional* incremental online correction hook
       (absent on immutable estimators such as the heuristic);
+  ``calibrate(measure)``  *optional* hook: ``measure()`` returns the
+      serving device's per-slice overhead in seconds (the heuristic's
+      one constant), called by the scheduler before its first cold tune;
   ``fork()``            a refit-isolated copy (per-tenant copy-on-refit);
   ``save(path)`` / ``load(path)``  versioned artifact round-trip
       (:mod:`repro.core.modeling.artifacts`).
@@ -47,9 +50,10 @@ def assemble_rows(prog_feats: np.ndarray, configs) -> np.ndarray:
 @runtime_checkable
 class Estimator(Protocol):
     """Structural type of everything the serving/tuning layers accept as
-    a model.  ``refit`` is deliberately absent: it is optional, and
-    callers feature-test it with ``hasattr`` (the heuristic and the
-    closed-form learners are immutable under serving)."""
+    a model.  ``refit`` and ``calibrate`` are deliberately absent: they
+    are optional, and callers feature-test them with ``hasattr`` (the
+    heuristic and the closed-form learners are immutable under serving;
+    only the heuristic calibrates)."""
 
     kind: str
 

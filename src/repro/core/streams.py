@@ -11,6 +11,7 @@ delegates to the ``mesh`` backend.
 """
 from __future__ import annotations
 
+import threading
 import time
 from typing import Callable, Union
 
@@ -190,6 +191,57 @@ def probe_host_capacity(workers: int, *, size: int = 384,
     jax.block_until_ready(jitk(dev))            # compile, untimed
     return parallel_capacity(
         [lambda: jax.block_until_ready(jitk(dev))], workers, reps=reps)
+
+
+def slice_probe(chunk: dict, shared: dict):
+    """The slice-overhead probe's kernel: vecadd's shape of work, two
+    chunked inputs and one output."""
+    return chunk["a"] + chunk["b"]
+
+
+#: the probe's payload (16 Ki float32 rows an input, 64 KiB) and split:
+#: at 1 KiB-row slices the bytes are negligible next to what a slice
+#: costs the host
+PROBE_ROWS = 16 * 1024
+PROBE_TASKS = 16
+_PROBE_WORKLOAD = Workload("slice_probe", "probe", slice_probe,
+                           make_data=None, datasets=())
+# (backend name, device) -> seconds; its own lock, held across the
+# probe so that engines sharing a process share one probe and one
+# compile (the probe's dispatches take the backends' memo lock)
+_SLICE_OVERHEAD: dict = {}
+_PROBE_LOCK = threading.Lock()
+
+
+def probe_slice_overhead(backend: Union[str, StreamBackend],
+                         device=None) -> float:
+    """Seconds each extra slice of a split costs on ``device`` under
+    ``backend``: a ``device_put`` per input, a kernel launch, a window
+    retire and a read-back per output.  A small payload runs at 1x1 and
+    at 1x``PROBE_TASKS``, interleaved (``profile_grid_interleaved``, min
+    over 5 sweeps, on ``run``'s basis: dispatch to read-back); the
+    overhead is their difference over the extra slices, never negative.
+    Memoized per process by (backend name, device)."""
+    backend = get_backend(backend) if isinstance(backend, str) else backend
+    device = device or jax.devices()[0]
+    key = (backend.name, device)
+    overhead_s = _SLICE_OVERHEAD.get(key)
+    if overhead_s is not None:
+        return overhead_s
+    with _PROBE_LOCK:
+        overhead_s = _SLICE_OVERHEAD.get(key)
+        if overhead_s is None:
+            rng = np.random.default_rng(0)
+            chunked = {k: rng.standard_normal(PROBE_ROWS, dtype=np.float32)
+                       for k in ("a", "b")}
+            runner = StreamedRunner(_PROBE_WORKLOAD, chunked, {},
+                                    device=device, backend=backend)
+            split = StreamConfig(1, PROBE_TASKS)
+            t = profile_grid_interleaved(runner, [SINGLE_STREAM, split],
+                                         sweeps=5)
+            overhead_s = _SLICE_OVERHEAD[key] = max(
+                0.0, (t[split] - t[SINGLE_STREAM]) / (PROBE_TASKS - 1))
+    return overhead_s
 
 
 def profile_config_grid(runner: StreamedRunner, configs, *, reps: int = 3,

@@ -327,6 +327,7 @@ def adaptive_serve(
         summary["slo_ms"] = slo_ms
         summary["shed"] = len(sched.queue.shed)
         summary["resilience"] = policy_obj is not None
+        summary["slice_overhead_us"] = sched.stats.get("slice_overhead_us")
         if faults is not None:
             summary["faults_injected"] = faults.fired
         if cache_path:

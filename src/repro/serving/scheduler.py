@@ -23,6 +23,7 @@ from __future__ import annotations
 
 import collections
 import dataclasses
+import functools
 import random
 from typing import Optional, Sequence
 
@@ -38,7 +39,8 @@ from repro.core.modeling.heuristic import OverlapHeuristicModel  # noqa: F401
 from repro.core.modeling.search import search_best, search_best_batch
 from repro.core.stream_config import SINGLE_STREAM, StreamConfig, \
     default_space
-from repro.core.streams import StreamedRunner, readback_outputs
+from repro.core.streams import StreamedRunner, probe_slice_overhead, \
+    readback_outputs
 from repro.core.workloads import get_workload
 from repro.serving.clock import SystemClock
 from repro.serving.observability import NULL_METRICS, NULL_TRACER, STAGES
@@ -363,6 +365,28 @@ class AdaptiveScheduler:
         self._t_single[pending.key] = float(values[_I_T_SINGLE]) * 1e-6
         return values
 
+    def _calibrate(self, device) -> None:
+        """Hand every model with the optional ``calibrate`` hook
+        (feature-tested like ``refit``) the per-slice overhead measured
+        on ``device`` with this scheduler's backend; a model measures
+        once and keeps it.  Runs on the coordinator before every cold
+        tune and refinement, when the engine's pool is drained.  The
+        probe is memoized per process, so later schedulers on the same
+        device read it free."""
+
+        @functools.cache
+        def measure() -> float:
+            with self.tracer.span("tune.calibrate") as span:
+                overhead_s = probe_slice_overhead(self.backend_name, device)
+                if self.tracer.enabled:
+                    span.attrs = {"overhead_us": overhead_s * 1e6}
+            self.stats["slice_overhead_us"] = overhead_s * 1e6
+            return overhead_s
+
+        for model in (self.model, getattr(self, "_fallback_model", None)):
+            if hasattr(model, "calibrate"):
+                model.calibrate(measure)
+
     def _model_for(self, pending: PendingRequest):
         """The model that ranks configs for this request: the tenant's
         fork once it has refitted, the shared base before that."""
@@ -372,6 +396,7 @@ class AdaptiveScheduler:
 
     def _tune_cold(self, pending: PendingRequest, *,
                    model=None, source: str = "model") -> TuneResult:
+        self._calibrate(pending.runner.device)
         t0 = self.clock.now()
         with self.tracer.span("tune.cold", trace_id=pending.req.trace_id,
                               workload=pending.req.workload):
@@ -412,6 +437,7 @@ class AdaptiveScheduler:
             by_key.setdefault(p.key, p)
         uniques = list(by_key.values())
 
+        self._calibrate(uniques[0].runner.device)
         t_batch0 = self.clock.now()
         self._m_batch_size.observe(len(uniques))
         with self.tracer.span("tune.cold.batch",
@@ -781,6 +807,7 @@ class AdaptiveScheduler:
         refines inline; the engine overrides this to DEFER the
         re-profiling to its next pool-quiesce point, so refinement
         measurements — like all profiling — happen on an idle pool."""
+        self._calibrate(pending.runner.device)
         with self.tracer.span("refine", trace_id=pending.req.trace_id,
                               key=key):
             self.faults.fire("refine")
