@@ -87,7 +87,8 @@ def readings(root: Path, workload: str, seeds: list, seconds: float,
         row = {"seed": seed, "correct": line["correct"],
                "attempted": line["attempted"],
                "metrics": {k: v["value"] for k, v in line["metrics"].items()},
-               "program": cr.gaps()}
+               "program": {p: float(c["value"])
+                           for p, c in line["checks"].items()}}
         if i < control_seeds:
             from precision import LowerOnDevice
             of = control_outputs(LowerOnDevice())

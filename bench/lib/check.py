@@ -14,9 +14,17 @@ program's ``combine`` in the configuration:
               under cuts them.
 
 The number compared for a request is the largest absolute difference
-over the largest absolute reference value (the absolute difference
-where the reference is all zeros); a missing output, a wrong shape or a
-value that is not finite reads infinity.  A cell's number for a program
+over a scale (the absolute difference where the scale is 0): the
+largest absolute reference value, and for a ``sum`` program the largest
+absolute value of the reference run on the magnitudes of its float
+inputs.  A ``sum`` program adds terms over all of a request's rows, so
+its answer can cancel to near zero while its rounding grows with the
+terms it adds: over its own answer the gap swings by orders of
+magnitude from request to request, and the widest over a run follows
+the smallest answer drawn.  Its kernels add products of their inputs,
+so the reference on magnitudes is the sum of the terms' magnitudes.  A
+missing output, a wrong shape or a value that is not finite reads
+infinity.  A cell's number for a program
 is the largest over its checked requests, held against the program's
 limit in the configuration.
 """
@@ -51,6 +59,11 @@ def _as(P, d: dict) -> dict:
     return {k: P.arr(v) for k, v in d.items()}
 
 
+def magnitudes(d: dict) -> dict:
+    """Float arrays in absolute value; indices and counts as they are."""
+    return {k: np.abs(v) if v.dtype.kind == "f" else v for k, v in d.items()}
+
+
 def expected(ref, P, combine: str, chunked: dict, shared: dict,
              split: tuple[int, int]) -> list:
     """The reference's answer in the shape the comparison needs: one
@@ -76,24 +89,29 @@ def combined(outs: list, combine: str) -> list:
     raise ValueError(f"unknown combine {combine!r}")
 
 
-def gap(got: list, want: list) -> float:
-    """The number compared for one request (see the module docstring)."""
+def gap(got: list, want: list, scales: list | None = None) -> float:
+    """The number compared for one request (see the module docstring):
+    ``scales`` in the shape of ``want``, ``want`` itself when None."""
     if len(got) != len(want) or not want:
         return math.inf
     worst, scale = 0.0, 0.0
-    for g, w in zip(got, want):
+    for g, w, m in zip(got, want, want if scales is None else scales):
         if g.shape != w.shape or not np.all(np.isfinite(g)):
             return math.inf
         if g.size:
             worst = max(worst, float(np.max(np.abs(g - w))))
-            scale = max(scale, float(np.max(np.abs(w))))
+            scale = max(scale, float(np.max(np.abs(m))))
     return worst / scale if scale > 0 else worst
 
 
 def request_gap(ref, combine: str, chunked: dict, shared: dict,
                 split: tuple[int, int], outs: list) -> float:
     want = expected(ref, REFERENCE, combine, chunked, shared, split)
-    return gap(combined(outs, combine), want)
+    scales = None
+    if combine == "sum":
+        scales = expected(ref, REFERENCE, combine, magnitudes(chunked),
+                          magnitudes(shared), split)
+    return gap(combined(outs, combine), want, scales)
 
 
 def verdict(gaps: dict, limits: dict) -> tuple[bool, dict]:

@@ -10,7 +10,8 @@ annotation, which ties the trace's clock to the host's
 ``time.perf_counter``.
 
 ``load`` reduces a trace file to plain event lists; ``reduce`` turns
-those into the numbers the readers use.  The committed test trace is
+one chip's into the numbers the readers use, and ``combine`` makes one
+reading of several chips'.  The committed test trace is
 the output of ``load`` on a chip trace, so the reduction is checked
 without a chip.
 """
@@ -108,6 +109,28 @@ def reduce(events: dict, lo_ns: float, hi_ns: float) -> dict:
         "op_totals_s": {k: v * 1e-9 for k, v in totals.items()},
         "kernel_s": kernel_ns * 1e-9,
         "kernel_calls": n_modules,
+    }
+
+
+def combine(reds: list) -> dict:
+    """Several chips' reductions of one sub-window as one: busy time,
+    kernel time, kernel calls and each op's total summed over the chips,
+    and the window the sub-window times the number of chips, so that
+    ``1 - busy_s / window_s`` is the chips' mean idle share.  The gaps
+    are each chip's in turn, on its own trace's clock: their lengths
+    compare across chips, their times do not.  One chip's reduction
+    comes back equal."""
+    totals: dict = {}
+    for red in reds:
+        for name, s in red["op_totals_s"].items():
+            totals[name] = totals.get(name, 0.0) + s
+    return {
+        "window_s": sum(red["window_s"] for red in reds),
+        "busy_s": sum(red["busy_s"] for red in reds),
+        "gaps_ns": [g for red in reds for g in red["gaps_ns"]],
+        "op_totals_s": totals,
+        "kernel_s": sum(red["kernel_s"] for red in reds),
+        "kernel_calls": sum(red["kernel_calls"] for red in reds),
     }
 
 

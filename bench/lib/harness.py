@@ -5,6 +5,10 @@ Everything that belongs to one configuration, traffic mix, driver or
 metric is a file of its own, found by the name ``BENCHMARK.json`` gives:
 
   <bench>/configs/<config>.json     programs, sizes, engine, limits
+  <bench>/engines/<kind>.py         ``open(config, traffic, ...)``: the
+                                    serving engine the configuration's
+                                    ``engine.kind`` names (``concurrent``
+                                    when absent; ``engine_api``)
   <bench>/traffic/<mix>.json        the driver's name and parameters
   <bench>/drivers/<driver>.py       ``drive(window, traffic)``
   <bench>/metrics/<metric>.py       ``read(run) -> float | None``, and
@@ -13,9 +17,11 @@ metric is a file of its own, found by the name ``BENCHMARK.json`` gives:
   <bench>/counts/<program>.py       ``counts(rows) -> (flops, bytes)``
   <bench>/reference/<program>.py    ``kernel(P, chunked, shared)``
 
-From the program the harness takes only the serving engine it drives
-(``ConcurrentScheduler`` on the heuristic model), the runner it warms
-shapes with, and the engine's spans.
+The harness keeps what is the benchmark's: data, the request stream and
+the window, the drivers, the check against the reference, the metric
+readers and the result line.  It opens no chip itself: the engine says
+which chips it serves on and hands back their memory peaks, traces and
+the program's spans, so the chips may belong to other processes.
 """
 from __future__ import annotations
 
@@ -23,7 +29,6 @@ import dataclasses
 import gc
 import importlib.util
 import json
-import math
 import os
 import shutil
 import sys
@@ -31,14 +36,12 @@ import time
 from concurrent.futures import ThreadPoolExecutor
 from pathlib import Path
 
-import numpy as np
-
 import check
 import datagen
 import devtrace
+import engine_api
 import trafficgen
 
-COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 #: host threads the reference comparison runs on
 CHECK_THREADS = 4
 #: the profiled sub-window of a traced run: the first driver call that
@@ -81,6 +84,10 @@ class Cell:
     end_to_end: list
     per_layer: list
     bench_dir: Path
+
+    @property
+    def engine_kind(self) -> str:
+        return engine_api.kind_of(self.config)
 
 
 def resolve(root: Path, name: str) -> Cell:
@@ -142,7 +149,8 @@ class Run:
     done: list = dataclasses.field(default_factory=list)
     spans: list = dataclasses.field(default_factory=list)
     compile_times: list = dataclasses.field(default_factory=list)
-    device: dict | None = None        # devtrace.reduce of the sub-window
+    device: dict | None = None        # devtrace.combine of the chips'
+    #                                   reductions of the sub-window
     peaks: dict | None = None
     picks: dict = dataclasses.field(default_factory=dict)
     store: dict = dataclasses.field(default_factory=dict)
@@ -183,120 +191,59 @@ class CellRun:
         self.platform = platform
         self.checked: list[Checked] = []
         self.attempted = 0
-        self._profiling = None          # (t_lo, t_anchor) while on
+        self._profiling = None          # t_lo while on
         self._trace_lo = self._trace_hi = None
         self._work = self.cell.bench_dir / ".work"
+        self._opener = load_plugin(self.cell.bench_dir, "engines",
+                                   self.cell.engine_kind)
+        self.engine = None
 
     # -- set-up ---------------------------------------------------------------
 
     def setup(self) -> None:
-        import jax
-
-        devs = jax.devices()
-        dev = devs[0]
-        if self.platform is not None:
-            if dev.platform != self.platform:
-                raise BenchError(f"jax.devices()[0] is {dev.platform!r} "
-                                 f"({dev.device_kind}), not {self.platform!r}")
-            if len(devs) < self.cell.chips:
-                raise BenchError(f"{len(devs)} chips, the cell asks for "
-                                 f"{self.cell.chips}")
-            self.run.peaks = peaks_for(self.cell.bench_dir, dev.device_kind)
-        self.device = dev
-        self.n_devices = min(len(devs), self.cell.chips)
-        jax.monitoring.register_event_duration_secs_listener(self._on_event)
-
-        from repro.core.streams import StreamedRunner
-        from repro.core.stream_config import SINGLE_STREAM
-        from repro.core.workloads import get_workload
-        from repro.serving.observability import Tracer
-
         cfg, tr = self.cell.config, self.cell.traffic
-        self.tracer = Tracer() if self.run.trace else None
-        self.engine = self._engine(self.tracer)
-        self._stamp_pops(self.engine)
+        setups = []
+        if self.run.trace:
+            shutil.rmtree(self._work, ignore_errors=True)
+            self._work.mkdir(parents=True)
+            setups = [m["name"] for m in self.cell.per_layer
+                      if hasattr(load_plugin(self.cell.bench_dir, "metrics",
+                                             m["name"]), "setup")]
+        if setups and not self._opener.OWN_CHIPS:
+            raise BenchError(
+                f"engine {self.cell.engine_kind!r} serves on chips of other "
+                f"processes; metrics that set up on this process's chips "
+                f"cannot read it: {setups}")
+        self.engine = self._opener.open(cfg, tr, trace=self.run.trace,
+                                        work=self._work)
+        self.chips = self.engine.chips()
+        if self.platform is not None:
+            for i, c in enumerate(self.chips):
+                if c.platform != self.platform:
+                    raise BenchError(f"chip {i} is {c.platform!r} ({c.kind}),"
+                                     f" not {self.platform!r}")
+            if len(self.chips) != self.cell.chips:
+                raise BenchError(f"{len(self.chips)} chips, the cell asks "
+                                 f"for {self.cell.chips}")
+            self.run.peaks = peaks_for(self.cell.bench_dir,
+                                       self.chips[0].kind)
 
         self.buckets = self._make_data()
         views = {b: (datagen.request_view(ch, 0, b[1]), sh)
                  for b, (ch, sh) in self.buckets.items()}
-        _ = self.engine.parallel_capacity     # the probe run() would make
         tenants = [f"tenant-{i}" for i in range(int(tr["tenants"]))]
-        if tr["tuning_cache"] == "filled":
-            who = tenants if tr["isolate_tenants"] else tenants[:1]
-            self._serve_once(self.engine, [(t, b) for t in who
-                                           for b in views], views)
-        elif tr["tuning_cache"] == "empty":
-            # a throwaway engine cold-tunes every bucket, each under a
-            # tenant of its own: buckets whose row counts round to one
-            # cache bucket would otherwise share one feature extraction,
-            # and the others' shapes would compile in the window
-            scratch = self._engine(None, isolate=True)
-            self._serve_once(scratch, [(f"warm-{i}", b)
-                                       for i, b in enumerate(views)], views)
-            scratch.close()
-        else:
-            raise BenchError(f"tuning_cache {tr['tuning_cache']!r}")
-        # every chunk shape a candidate split can give, so that a cold
-        # tune or a drift refinement in the window compiles nothing: a
-        # split's kernel calls take floor or ceil of rows / (partitions *
-        # tasks) rows, and each such shape is warmed by one single-stream
-        # dispatch of that many rows
-        backend = cfg["engine"]["backend"]
-        qs = {c.partitions * c.tasks for c in self.engine.candidates}
-        for (prog, rows), (ch, sh) in views.items():
-            sizes = {f(rows / q) for q in qs if q <= rows
-                     for f in (math.floor, math.ceil)}
-            for m in sorted(sizes):
-                StreamedRunner(get_workload(prog),
-                               datagen.request_view(ch, 0, m), sh,
-                               backend=backend).warmup(SINGLE_STREAM)
-        if self.run.trace:
+        self.run.picks = self.engine.setup(views, tenants)
+        if setups:
             self.run.buckets = views
-            for m in self.cell.per_layer:
-                mod = load_plugin(self.cell.bench_dir, "metrics", m["name"])
-                if hasattr(mod, "setup"):
-                    mod.setup(self.run)
+            for name in setups:
+                load_plugin(self.cell.bench_dir, "metrics", name).setup(
+                    self.run)
             self.run.buckets = {}
-            shutil.rmtree(self._work, ignore_errors=True)
-            self._work.mkdir(parents=True)
         self.items = trafficgen.request_items(cfg, tr, self.run.seed)
         # set-up's objects leave the collector's generations, so that a
         # full collection in the window does not walk them
         gc.collect()
         gc.freeze()
-
-    def _engine(self, tracer, isolate: bool | None = None):
-        from repro.core.modeling.heuristic import OverlapHeuristicModel
-        from repro.serving.engine import ConcurrentScheduler
-        from repro.serving.refinement import DriftDetector
-
-        eng = self.cell.config["engine"]
-        if eng["model"] != "heuristic":
-            raise BenchError(f"model {eng['model']!r} is not supported")
-        # "drift": "off" is a detector that never fires: no refinement
-        # re-profiles in the window; the engine's own detector otherwise
-        drift = {"on": None, "off": DriftDetector(threshold=math.inf)}[
-            eng.get("drift", "on")]
-        return ConcurrentScheduler(
-            OverlapHeuristicModel(), window=int(eng["window"]),
-            workers=int(eng["workers"]), backend=eng["backend"],
-            isolate_tenants=bool(self.cell.traffic["isolate_tenants"]
-                                 if isolate is None else isolate),
-            drift=drift, tracer=tracer)
-
-    def _stamp_pops(self, engine) -> None:
-        """Stamp each request when the engine takes it off its queue, on
-        the harness's clock (the engine's clock is the same
-        ``perf_counter``)."""
-        self.popped: dict = {}
-        pop = engine.queue.pop
-
-        def stamped():
-            req = pop()
-            self.popped[req.seq] = time.perf_counter()
-            return req
-
-        engine.queue.pop = stamped
 
     def _make_data(self) -> dict:
         progs = self.cell.config["programs"]
@@ -305,26 +252,6 @@ class CellRun:
                                                    self.run.seed, pool)
                     for p, spec in sorted(progs.items())
                     for rows in spec["rows"]}
-
-    def _serve_once(self, engine, who: list, views: dict) -> None:
-        """Serve one request of each ``(tenant, bucket)`` in ``who``;
-        the picks of the window's engine are kept for the readers."""
-        from repro.serving.queue import WorkloadRequest
-
-        reqs = {}
-        for t, bucket in who:
-            ch, sh = views[bucket]
-            req = WorkloadRequest(bucket[0], ch, sh, tenant=t)
-            reqs[id(req)] = bucket
-            engine.submit(req)
-        for r in engine.run():
-            if engine is self.engine:
-                self.run.picks[reqs[id(r.request)]] = (r.config.partitions,
-                                                      r.config.tasks)
-
-    def _on_event(self, event: str, duration: float, **kw) -> None:
-        if event == COMPILE_EVENT:
-            self.run.compile_times.append(time.perf_counter())
 
     def _on_gc(self, phase: str, info: dict) -> None:
         """Each garbage collection of the window: (generation, seconds)."""
@@ -342,8 +269,7 @@ class CellRun:
         t0 = time.perf_counter()
         self.run.setup_s = t0 - self.t_process
         self.run.t_start = t0
-        if self.tracer is not None:
-            self.tracer.clear()
+        self.engine.begin()
         self._trace_from = t0 + TRACE_AFTER_S
         self.gc_pauses: list = []
         gc.callbacks.append(self._on_gc)
@@ -352,98 +278,60 @@ class CellRun:
             self._stop_profile(time.perf_counter())
         self.run.t_end = time.perf_counter()
         gc.callbacks.remove(self._on_gc)
-        if self.tracer is not None:
-            self.run.spans = [s for s in self.tracer.spans
-                              if s.t_start >= self.run.t_start]
 
     def serve(self, k: int) -> None:
-        from repro.serving.queue import WorkloadRequest
-
-        batch = {}
-        for _ in range(k):
+        batch = []
+        for key in range(k):
             it = next(self.items)
             ch, sh = self.buckets[(it.program, it.rows)]
-            req = WorkloadRequest(it.program,
-                                  datagen.request_view(ch, it.offset, it.rows),
-                                  sh, tenant=it.tenant)
-            batch[id(req)] = it
-            self.engine.submit(req)
+            batch.append((it, engine_api.Request(
+                key=key, program=it.program, tenant=it.tenant,
+                chunked=datagen.request_view(ch, it.offset, it.rows),
+                shared=sh, check=it.check)))
         self.attempted += k
         now = time.perf_counter()
         if (self.run.trace and self._profiling is None
                 and self._trace_lo is None and now >= self._trace_from):
-            self._start_profile()
+            self._profiling = time.perf_counter()
+            self.engine.start_profile()
         traced = self._profiling is not None
-        results = self.engine.run()
+        served = self.engine.serve([q for _, q in batch])
         now = time.perf_counter()
-        if traced and now - self._profiling[0] >= TRACE_FOR_S:
+        if traced and now - self._profiling >= TRACE_FOR_S:
             self._stop_profile(now)
-        for r in results:
-            it = batch[id(r.request)]
-            ok = r.status in ("served", "degraded")
-            split = ((r.config.partitions, r.config.tasks)
-                     if r.config is not None else (0, 0))
+        for s in served:
+            it = batch[s.key][0]
             self.run.done.append(Done(
                 program=it.program, rows=it.rows, tenant=it.tenant,
-                split=split, t_pop=self.popped.pop(r.request.seq, math.nan),
-                t_retire=r.sample.t_retire_s,
-                in_bytes=_nbytes(r.request.chunked) + _nbytes(r.request.shared),
-                out_bytes=sum(int(o.nbytes) for o in r.outputs),
-                ok=ok, traced=traced))
+                split=s.split, t_pop=s.t_pop, t_retire=s.t_retire,
+                in_bytes=s.in_bytes, out_bytes=s.out_bytes, ok=s.ok,
+                traced=traced))
             if it.check:
-                self.checked.append(Checked(
-                    item=it, split=split,
-                    outputs=[np.asarray(o) for o in r.outputs]))
-
-    def _start_profile(self) -> None:
-        import jax
-
-        opts = jax.profiler.ProfileOptions()
-        opts.python_tracer_level = 0
-        lo = time.perf_counter()
-        jax.profiler.start_trace(str(self._work), profiler_options=opts)
-        with jax.profiler.TraceAnnotation(devtrace.ANCHOR):
-            anchor = time.perf_counter()
-        self._profiling = (lo, anchor)
+                self.checked.append(Checked(item=it, split=s.split,
+                                            outputs=s.outputs))
 
     def _stop_profile(self, hi: float) -> None:
-        import jax
-
-        jax.profiler.stop_trace()
-        self._trace_lo, self._anchor = self._profiling
-        self._trace_hi = hi
+        self.engine.stop_profile()
+        self._trace_lo, self._trace_hi = self._profiling, hi
         self._profiling = None
 
     # -- after the window -----------------------------------------------------
 
     def finish(self) -> dict:
-        """Memory peak, then the program's state freed, then the trace,
-        the check and the metrics; returns the result line."""
-        import jax
-
-        stats = self.device.memory_stats() or {}
-        peak = int(stats.get("peak_bytes_in_use", 0))
-        jax.monitoring.unregister_event_duration_listener(self._on_event)
-        self.engine.close()
-        self.engine = None
+        """The engine's spans, memory peaks and traces, then its state
+        freed, then the device reduction, the check and the metrics;
+        returns the result line."""
+        got = self.engine.collect()
+        self.close()
         gc.unfreeze()
         gc.collect()
-
+        self.run.compile_times = got.compile_times
+        self.run.spans = [s for s in got.spans
+                          if s.t_start >= self.run.t_start]
         breakdown = None
-        if self.run.trace and self._trace_lo is not None:
-            path = devtrace.find_xplane(str(self._work))
-            events = devtrace.load(path) if path else None
-            if events and events["anchor_ns"] is not None and events["ops"]:
-                to_ns = self._to_ns(events["anchor_ns"])
-                red = devtrace.reduce(events, to_ns(self._trace_lo),
-                                      to_ns(self._trace_hi))
-                self.run.device = red
-                top = sorted(red["op_totals_s"].items(),
-                             key=lambda kv: -kv[1])[:10]
-                breakdown = {
-                    "device_ops": [[k, v] for k, v in top],
-                    "idle_gaps": devtrace.name_gaps(red["gaps_ns"],
-                                                    self.run.spans, to_ns)}
+        if self.run.trace:
+            if self._trace_lo is not None:
+                breakdown = self._reduce_traces(got.traces)
             shutil.rmtree(self._work, ignore_errors=True)
 
         t_check = time.perf_counter()
@@ -466,9 +354,9 @@ class CellRun:
             value = mod.read(self.run)
             if value is not None:
                 metrics[m["name"]] = {"value": value, "unit": m["unit"]}
-        device = {"platform": self.device.platform,
-                  "kind": self.device.device_kind,
-                  "count": self.n_devices, "memory_peak_bytes": peak}
+        device = {"platform": self.chips[0].platform,
+                  "kind": self.chips[0].kind, "count": len(self.chips),
+                  "memory_peak_bytes": max(got.memory_peaks, default=0)}
         if self.run.device is not None:
             device["busy_s"] = self.run.device["busy_s"]
             device["window_s"] = self.run.device["window_s"]
@@ -479,9 +367,36 @@ class CellRun:
         line["checks"] = checks
         return line
 
-    def _to_ns(self, anchor_ns: float):
-        anchor = self._anchor
-        return lambda t: anchor_ns + (t - anchor) * 1e9
+    def close(self) -> None:
+        """Stop the engine, and every process it started, if it is open."""
+        engine, self.engine = self.engine, None
+        if engine is not None:
+            engine.close()
+
+    def _reduce_traces(self, traces: list) -> dict | None:
+        """Each chip's trace over the profiled sub-window, against its
+        own anchor, combined into ``run.device``; returns the breakdown:
+        the device ops that took most time over the chips, and the
+        longest idle gaps of any chip, each named by the spans of the
+        process that holds the chip.  Nothing when a trace has no
+        anchor or no chip ran an op."""
+        if (not traces or any(t.events["anchor_ns"] is None for t in traces)
+                or not any(t.events["ops"] for t in traces)):
+            return None
+        reds, gaps = [], []
+        for t in traces:
+            to_ns = _to_ns(t.events["anchor_ns"], t.anchor)
+            red = devtrace.reduce(t.events, to_ns(self._trace_lo),
+                                  to_ns(self._trace_hi))
+            reds.append(red)
+            gaps += devtrace.name_gaps(
+                red["gaps_ns"], [s for s in self.run.spans
+                                 if s.proc == t.proc], to_ns)
+        self.run.device = devtrace.combine(reds)
+        top = sorted(self.run.device["op_totals_s"].items(),
+                     key=lambda kv: -kv[1])[:10]
+        return {"device_ops": [[k, v] for k, v in top],
+                "idle_gaps": sorted(gaps, key=lambda g: -g[1])[:10]}
 
     def gaps(self, outputs_of=None) -> dict:
         """Program -> largest gap over its checked requests, the
@@ -512,8 +427,10 @@ class CellRun:
         return check.verdict(self.gaps(), self.cell.config["limits"])
 
 
-def _nbytes(d: dict) -> int:
-    return sum(int(a.nbytes) for a in d.values())
+def _to_ns(anchor_ns: float, anchor: float):
+    """Host-clock seconds to a trace's nanoseconds, through an instant
+    both clocks stamped."""
+    return lambda t: anchor_ns + (t - anchor) * 1e9
 
 
 def peaks_for(bench_dir: Path, kind: str) -> dict:
@@ -529,9 +446,12 @@ def run_cell(root: Path, name: str, seed: int, seconds: float, trace: bool,
     """One whole run; returns the result line's object."""
     cr = CellRun(root, name, seed, seconds, trace, t_process=t_process,
                  platform=platform)
-    cr.setup()
-    cr.window()
-    return cr.finish()
+    try:
+        cr.setup()
+        cr.window()
+        return cr.finish()
+    finally:
+        cr.close()
 
 
 def report(line: dict) -> None:

@@ -78,3 +78,33 @@ def test_call_rows_is_array_split():
     want = [(int(p[0]), int(p[-1]) + 1) for t in np.array_split(idx, 7)
             for p in np.array_split(t, 3)]
     assert got == want
+
+
+@pytest.mark.parametrize("program", ["scalarprod", "mri-gridding"])
+def test_sum_programs_compare_over_their_terms_magnitudes(program):
+    """A ``sum`` program's answer can cancel to near zero: its gap is
+    taken over the reference on its inputs' magnitudes, so float32
+    rounding reads the same small number whatever the draw, and an
+    answer off by a part in a thousand of its terms still fails."""
+    from repro.core.workloads import get_workload
+
+    wl = get_workload(program)
+    c, s = wl.make_data(256, np.random.default_rng(5))
+    key = "a" if program == "scalarprod" else "val"
+    # cancel the reference: the second half is the first half negated
+    half = c[key][:128].copy()
+    c[key][128:] = -half
+    if program == "scalarprod":
+        c["b"][128:] = c["b"][:128]
+    else:
+        c["idx"][128:] = c["idx"][:128]
+    outs = [np.asarray(jax.jit(wl.kernel)(c, s))]
+    ref = _ref(program)
+    want = check.expected(ref, REFERENCE, "sum", c, s, (1, 1))
+    scale = check.expected(ref, REFERENCE, "sum", check.magnitudes(c),
+                           check.magnitudes(s), (1, 1))
+    assert np.max(np.abs(want[0])) < 1e-6 * np.max(scale[0])
+    assert check.request_gap(ref, "sum", c, s, (1, 1), outs) < 1e-6
+    off = [o + 1e-3 * np.max(scale[0]) for o in outs]
+    assert check.request_gap(ref, "sum", c, s, (1, 1), off) \
+        == pytest.approx(1e-3, rel=1e-3)

@@ -27,6 +27,43 @@ def test_reduce_by_hand():
     assert red["kernel_calls"] == 2
 
 
+def test_combine_two_chips_by_hand():
+    """Two chips over one sub-window of 40 ns, each trace on a clock of
+    its own: busy, kernel time and op totals add up, the window is the
+    sub-window twice, so the idle share is the chips' mean (1 - 20/40
+    and 1 - 5/40: 0.6875), and each chip keeps its own gaps."""
+    chip0 = {"ops": [["a", 0, 10], ["b", 5, 10], ["c", 30, 5]],
+             "modules": [["m1", 0, 15], ["m2", 30, 5]]}
+    chip1 = {"ops": [["a", 1010, 5]], "modules": [["m1", 1010, 5]]}
+    red = devtrace.combine([devtrace.reduce(chip0, 0, 40),
+                            devtrace.reduce(chip1, 1000, 1040)])
+    assert red["busy_s"] == pytest.approx(25e-9)      # 20 + 5
+    assert red["window_s"] == pytest.approx(80e-9)    # 40 twice
+    assert 1 - red["busy_s"] / red["window_s"] == pytest.approx(
+        (0.5 + 0.875) / 2)
+    assert red["gaps_ns"] == [[15, 30], [35, 40], [1000, 1010], [1015, 1040]]
+    assert red["op_totals_s"] == pytest.approx({"a": 15e-9, "b": 10e-9,
+                                                "c": 5e-9})
+    assert red["kernel_s"] == pytest.approx(25e-9)    # 15 + 5 + 5
+    assert red["kernel_calls"] == 3
+
+
+@pytest.mark.parametrize("recorded", [False, True])
+def test_combine_of_one_chip_is_its_reduction(recorded):
+    """A cell on one chip reads what it read before several chips could
+    be combined: every field equal, not approximately."""
+    if recorded:
+        events, (lo, hi) = RECORDED, RECORDED["window_ns"]
+    else:
+        events, lo, hi = {"ops": [["a", 0, 10], ["b", 5, 10]],
+                          "modules": [["m1", 0, 15]]}, 3, 40
+    red = devtrace.reduce(events, lo, hi)
+    combined = devtrace.combine([red])
+    assert list(combined) == list(red)
+    for field, value in red.items():
+        assert combined[field] == value, field
+
+
 def test_gaps_named_by_open_spans():
     span = lambda name, t0, t1, depth=0: types.SimpleNamespace(  # noqa: E731
         name=name, t_start=t0, t_end=t1, depth=depth)

@@ -51,6 +51,23 @@ def test_programs_tenants_and_datasets_are_uniform():
         assert max(rows.values()) - min(rows.values()) <= trafficgen.DECK
 
 
+@pytest.mark.parametrize("seed", [4, 2 ** 31 + 17])
+def test_warm4_blocks_hold_every_program_4_and_every_tenant_39_times(seed):
+    """``suite39.warm``'s mix: each block of 156 sends each of the 39
+    programs 4 times and each of the 4 tenants 39 times, whatever the
+    seed, so that a run's work does not hang on its seed."""
+    warm = json.loads((ROOT / "bench/traffic/uniform-warm4.json").read_text())
+    items = take(seed, 3 * warm["block"], traffic=warm)
+    for b in range(3):
+        block = items[b * warm["block"]:(b + 1) * warm["block"]]
+        assert set(collections.Counter(x.program for x in block).values()) \
+            == {4}
+        tenants = collections.Counter(x.tenant for x in block)
+        assert len(tenants) == 4 and set(tenants.values()) == {39}
+    assert abs(sum(x.check for x in items)
+               - len(items) / warm["check_every"]) < 1
+
+
 @pytest.mark.parametrize("seed", [5, 2 ** 31 + 9])
 def test_checks_spread_over_the_window(seed):
     every = COLD["check_every"]
